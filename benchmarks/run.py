@@ -34,6 +34,8 @@ def snapshot_meta() -> dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--table", default="all",
                     choices=["all", "1", "2", "e2e", "pipeline_plans",

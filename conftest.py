@@ -13,10 +13,16 @@ against the static LOCK edge model:
   (informational — dead path or coverage hole);
 * confirmed edges are printed so the cross-validation is visible.
 
-Without the env var this file does nothing at all.
+Without the env var the sanitizer stays off.
+
+Tests, and the processes they spawn, run without JAX's persistent
+compilation cache: entry points turn it on (``launch.compile_cache``), and
+a test that drives one must not write a cache into the checkout.
 """
 import os
 import sys
+
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 _ROOT = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_ROOT, "src")

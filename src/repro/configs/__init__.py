@@ -33,6 +33,10 @@ ASSIGNED_ARCHS = tuple(a for a in _MODULES if a != "sm-cnn")
 
 
 def get_config(arch: str):
+    """The published config of ``arch``; ``<arch>-smoke`` names its
+    ``reduced`` twin (the tiny same-family config CPU tests run)."""
+    if arch.endswith("-smoke") and arch[:-len("-smoke")] in _MODULES:
+        return reduced(get_config(arch[:-len("-smoke")]))
     if arch not in _MODULES:
         raise KeyError(f"unknown arch {arch!r}; known: {sorted(_MODULES)}")
     return importlib.import_module(_MODULES[arch]).CONFIG

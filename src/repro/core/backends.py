@@ -21,6 +21,7 @@ from typing import Callable, Dict, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import TextPairConfig
 from repro.core import compiled_artifact, export as export_lib, numpy_eval
@@ -40,10 +41,22 @@ def _bucket(n: int, buckets: Sequence[int]) -> int:
 class Scorer:
     """Uniform scoring interface over any integration backend."""
 
-    def __init__(self, fn: Callable, buckets: Sequence[int], name: str):
+    def __init__(self, fn: Callable, buckets: Sequence[int], name: str,
+                 params=None):
         self._fn = fn
         self._buckets = tuple(buckets)
         self.name = name
+        #: The params as placed for the device backends (None for numpy,
+        #: which evaluates its own exported copy on the host).
+        self.params = params
+
+    @property
+    def device(self):
+        """The jax device this scorer computes on (None for numpy)."""
+        if self.params is None:
+            return None
+        leaf = jax.tree.leaves(self.params)[0]
+        return next(iter(leaf.devices()))
 
     def __call__(self, q_tok, a_tok, feats) -> np.ndarray:
         n = q_tok.shape[0]
@@ -77,31 +90,11 @@ class Scorer:
 
 
 def make_scorer(backend: str, params: Dict, cfg: TextPairConfig,
-                buckets: Sequence[int] = (1, 8, 64, 256)) -> Scorer:
-    if backend == "eager":
-        fn = functools.partial(sm_cnn.score, params, cfg=cfg)
-        # block_until_ready via np.asarray in Scorer
-        return Scorer(lambda q, a, f: fn(jnp.asarray(q), jnp.asarray(a),
-                                         jnp.asarray(f)), buckets, backend)
-
-    if backend == "jit":
-        jfn = jax.jit(functools.partial(sm_cnn.score, cfg=cfg))
-        return Scorer(lambda q, a, f: jfn(params, q, a, f), buckets, backend)
-
-    if backend == "aot":
-        # weights closed over as constants; shape-specialized AOT compiles
-        frozen = jax.tree.map(jnp.asarray, params)
-        base = jax.jit(lambda q, a, f: sm_cnn.score(frozen, q, a, f, cfg))
-        compiled: Dict[int, Callable] = {}
-        for b in buckets:
-            specs = (jax.ShapeDtypeStruct((b, cfg.max_len), jnp.int32),
-                     jax.ShapeDtypeStruct((b, cfg.max_len), jnp.int32),
-                     jax.ShapeDtypeStruct((b, cfg.n_extra_feats), jnp.float32))
-            compiled[b] = base.lower(*specs).compile()
-        return Scorer(lambda q, a, f: compiled[q.shape[0]](
-            jnp.asarray(q, jnp.int32), jnp.asarray(a, jnp.int32),
-            jnp.asarray(f, jnp.float32)), buckets, backend)
-
+                buckets: Sequence[int] = (1, 8, 64, 256),
+                device=None) -> Scorer:
+    """A ``Scorer`` for ``backend``. ``device`` (a ``jax.Device``) pins its
+    params, compiled entries and inputs to that device; None keeps JAX's
+    default device."""
     if backend == "numpy":
         blob = export_lib.dumps(params, model=cfg.name,
                                 meta={"filter_width": cfg.filter_width})
@@ -109,25 +102,54 @@ def make_scorer(backend: str, params: Dict, cfg: TextPairConfig,
         return Scorer(lambda q, a, f: ev.get_score(np.asarray(q), np.asarray(a),
                                                    np.asarray(f)), buckets, backend)
 
+    params = jax.device_put(params, device)
+
+    def put(q, a, f):
+        return (jax.device_put(np.asarray(q, np.int32), device),
+                jax.device_put(np.asarray(a, np.int32), device),
+                jax.device_put(np.asarray(f, np.float32), device))
+
+    def specs(b):
+        sharding = None if device is None else SingleDeviceSharding(device)
+        return (jax.ShapeDtypeStruct((b, cfg.max_len), jnp.int32,
+                                     sharding=sharding),
+                jax.ShapeDtypeStruct((b, cfg.max_len), jnp.int32,
+                                     sharding=sharding),
+                jax.ShapeDtypeStruct((b, cfg.n_extra_feats), jnp.float32,
+                                     sharding=sharding))
+
+    if backend == "eager":
+        # block_until_ready via np.asarray in Scorer
+        return Scorer(lambda q, a, f: sm_cnn.score(params, *put(q, a, f),
+                                                   cfg=cfg),
+                      buckets, backend, params)
+
+    if backend == "jit":
+        jfn = jax.jit(functools.partial(sm_cnn.score, cfg=cfg))
+        return Scorer(lambda q, a, f: jfn(params, *put(q, a, f)),
+                      buckets, backend, params)
+
+    if backend == "aot":
+        # weights closed over as constants; shape-specialized AOT compiles
+        base = jax.jit(lambda q, a, f: sm_cnn.score(params, q, a, f, cfg))
+        compiled: Dict[int, Callable] = {
+            b: base.lower(*specs(b)).compile() for b in buckets}
+        return Scorer(lambda q, a, f: compiled[q.shape[0]](*put(q, a, f)),
+                      buckets, backend, params)
+
     if backend == "pallas":
         from repro.kernels import ops as kops
         jfn = jax.jit(functools.partial(kops.sm_cnn_score, cfg=cfg))
-        return Scorer(lambda q, a, f: jfn(params, q, a, f), buckets, backend)
+        return Scorer(lambda q, a, f: jfn(params, *put(q, a, f)),
+                      buckets, backend, params)
 
     if backend == "artifact":
-        frozen = jax.tree.map(jnp.asarray, params)
-        shapes = {f"b{b}": (
-            jax.ShapeDtypeStruct((b, cfg.max_len), jnp.int32),
-            jax.ShapeDtypeStruct((b, cfg.max_len), jnp.int32),
-            jax.ShapeDtypeStruct((b, cfg.n_extra_feats), jnp.float32))
-            for b in buckets}
         blob = compiled_artifact.build_artifact(
-            lambda q, a, f: sm_cnn.score(frozen, q, a, f, cfg), shapes,
-            meta={"model": cfg.name})
+            lambda q, a, f: sm_cnn.score(params, q, a, f, cfg),
+            {f"b{b}": specs(b) for b in buckets}, meta={"model": cfg.name})
         art = compiled_artifact.CompiledArtifact.from_bytes(blob)
-        return Scorer(lambda q, a, f: art.call(
-            f"b{q.shape[0]}", jnp.asarray(q, jnp.int32),
-            jnp.asarray(a, jnp.int32), jnp.asarray(f, jnp.float32)),
-            buckets, backend)
+        return Scorer(lambda q, a, f: art.call(f"b{q.shape[0]}",
+                                               *put(q, a, f)),
+                      buckets, backend, params)
 
     raise ValueError(f"unknown backend {backend!r}; known: {BACKENDS}")

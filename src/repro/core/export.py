@@ -29,11 +29,11 @@ SCHEMA_VERSION = 1
 
 
 def _flatten_named(params) -> Dict[str, np.ndarray]:
-    from repro.core.treepath import keystr
     flat = {}
     leaves = jax.tree_util.tree_flatten_with_path(params)[0]
     for path, leaf in leaves:
-        flat[keystr(path)] = np.asarray(leaf)
+        name = jax.tree_util.keystr(path, simple=True, separator="/")
+        flat[name] = np.asarray(leaf)
     return flat
 
 
@@ -99,11 +99,10 @@ def load(path: str) -> Tuple[Dict[str, np.ndarray], Dict]:
 def restore_into(template, flat: Dict[str, np.ndarray]):
     """Rebuild a pytree with the template's structure from named tensors
     (the Java-side 'reshape using saved dimension metadata' step)."""
-    from repro.core.treepath import keystr
     paths, treedef = jax.tree_util.tree_flatten_with_path(template)
     leaves = []
     for path, leaf in paths:
-        name = keystr(path)
+        name = jax.tree_util.keystr(path, simple=True, separator="/")
         if name not in flat:
             raise KeyError(f"tensor {name!r} missing from export")
         arr = flat[name]

@@ -25,7 +25,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.treepath import keystr
 from repro.distributed.mesh import axis_size, data_axes
 
 
@@ -135,7 +134,7 @@ def param_specs(params: Any, family: str, mesh) -> Any:
     rule = _FAMILY_RULES[family]
 
     def one(path, leaf):
-        name = keystr(path)
+        name = jax.tree_util.keystr(path, simple=True, separator="/")
         return rule(name, np.shape(leaf), mesh)
 
     return jax.tree_util.tree_map_with_path(one, params)
@@ -220,7 +219,7 @@ def batch_specs(batch: Any, family: str, kind: str, mesh) -> Any:
     if family == "gnn" and kind in ("graph_full", "graph_sampled"):
         # edges over ALL axes, node arrays replicated
         def gnn_rule(path, leaf):
-            name = keystr(path)
+            name = jax.tree_util.keystr(path, simple=True, separator="/")
             nd = np.ndim(leaf)
             if re.search(r"(edges|senders|receivers|edge_mask)$", name):
                 return P(every, *([None] * (nd - 1)))
@@ -229,7 +228,7 @@ def batch_specs(batch: Any, family: str, kind: str, mesh) -> Any:
 
     if family == "recsys" and kind == "rec_retrieval":
         def rec_rule(path, leaf):
-            name = keystr(path)
+            name = jax.tree_util.keystr(path, simple=True, separator="/")
             nd = np.ndim(leaf)
             if re.search(r"candidates$", name):
                 return P(every, *([None] * (nd - 1)))

@@ -32,7 +32,7 @@ def _kernel(ids_ref, w_ref, table_ref, o_ref):
 
         def one_hot_row(j, acc):
             idx = ids_ref[i, j]
-            row = pl.load(table_ref, (pl.dslice(idx, 1), slice(None)))[0]
+            row = table_ref[pl.ds(idx, 1), :][0]
             return acc + row.astype(jnp.float32) * w_ref[i, j]
 
         acc = jax.lax.fori_loop(0, l, one_hot_row, acc)

@@ -3,6 +3,10 @@
   # paper-faithful single-threaded server
   PYTHONPATH=src python -m repro.launch.serve --backend aot --port 9090
 
+  # the same at sm-cnn's published widths (default: the reduced
+  # sm-cnn-smoke config that tests and examples run)
+  PYTHONPATH=src python -m repro.launch.serve --config sm-cnn --port 9090
+
   # concurrent cluster: 4 replicas behind a thread-pool server with
   # power-of-two-choices routing and a bounded admission queue
   PYTHONPATH=src python -m repro.launch.serve --server threadpool \
@@ -49,6 +53,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.world import build_world
 from repro.core import backends as BK
 from repro.core import ops
@@ -187,8 +193,12 @@ def describe_plans(args, cfg, params, corpus, tok, index) -> str:
     return "\n".join(lines)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
+    ap.add_argument("--config", default="sm-cnn-smoke", metavar="NAME",
+                    help="registered model config to serve: 'sm-cnn' is "
+                         "the published widths, 'sm-cnn-smoke' its reduced "
+                         "twin")
     ap.add_argument("--backend", default="aot", choices=BK.BACKENDS)
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=0)
@@ -260,7 +270,24 @@ def main():
                          "query hash space to this registry version; "
                          "per-arm metrics carry model_version labels "
                          "(needs --serve-pipeline + --registry)")
-    args = ap.parse_args()
+    return ap
+
+
+def describe_device() -> str:
+    """Where this process computes: platform, device kind and count, and
+    whether Pallas kernels run interpreted."""
+    import jax
+    from repro.kernels.ops import interpret_default
+    devices = jax.devices()
+    return (f"platform={devices[0].platform} "
+            f"device_kind={devices[0].device_kind!r} "
+            f"devices={len(devices)} "
+            f"pallas_interpret={interpret_default()}")
+
+
+def main():
+    enable_compile_cache()
+    args = build_parser().parse_args()
 
     if args.swap:
         if args.port == 0:
@@ -282,7 +309,7 @@ def main():
         # The supervisor builds no world of its own — each worker process
         # trains/compiles independently (that is the point of the fabric).
         from repro.serving.fabric import Fabric
-        extra = []
+        extra = ["--config", args.config]
         if args.plan_target != "batched":
             extra += ["--plan-target", args.plan_target]
         if args.registry:
@@ -305,7 +332,8 @@ def main():
                 pass
         return
 
-    cfg, params, corpus, tok, index, _ = build_world(args.train_steps)
+    cfg, params, corpus, tok, index, _ = build_world(
+        args.train_steps, cfg=get_config(args.config))
     if args.describe:
         print(describe_plans(args, cfg, params, corpus, tok, index))
         return
@@ -315,8 +343,8 @@ def main():
             f"max_queue={args.max_queue}")
     if args.serve_pipeline:
         mode += " serve-pipeline(rank-rpc)"
-    print(f"serving QuestionAnswering ({args.backend}, {mode}) "
-          f"on {srv.address}")
+    print(f"serving QuestionAnswering ({cfg.name}, {args.backend}, {mode}) "
+          f"on {srv.address}; {describe_device()}")
     # Machine-readable discovery line for the fabric supervisor: workers
     # bind port 0, so this flushed line is how serving.fabric learns the
     # address (stdout is a PIPE there — without flush=True the line sits

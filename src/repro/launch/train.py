@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.configs import ASSIGNED_ARCHS, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.training.optimizer import adamw, warmup_cosine_schedule
 from repro.training.train_loop import Trainer
 
@@ -76,6 +77,7 @@ def build(arch: str, full: bool, batch: int, seq_len: int):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     choices=list(ASSIGNED_ARCHS) + ["sm-cnn"])

@@ -8,7 +8,7 @@ from typing import Dict, List, Tuple
 import jax
 import numpy as np
 
-from repro.configs import get_config, reduced
+from repro.configs import get_config
 from repro.core import bm25 as BM
 from repro.data import qa as QA
 from repro.data.tokenizer import HashingTokenizer
@@ -17,9 +17,13 @@ from repro.training.optimizer import adamw
 from repro.training.train_loop import Trainer
 
 
-def build_world(train_steps: int = 60, seed: int = 0):
-    """Returns (cfg, params, corpus, tokenizer, index, eval_pairs)."""
-    cfg = reduced(get_config("sm-cnn"))
+def build_world(train_steps: int = 60, seed: int = 0, cfg=None):
+    """Returns (cfg, params, corpus, tokenizer, index, eval_pairs).
+
+    ``cfg`` defaults to the reduced sm-cnn that tests and examples run;
+    pass ``get_config("sm-cnn")`` for the published widths. Raises
+    ``FloatingPointError`` if the last training loss is not finite."""
+    cfg = cfg or get_config("sm-cnn-smoke")
     corpus = QA.generate_corpus(n_docs=80, n_questions=60, seed=seed)
     tok = HashingTokenizer(cfg.vocab_size)
     index = BM.build_index([tok.encode(" ".join(d)) for d in corpus.documents],
@@ -33,7 +37,10 @@ def build_world(train_steps: int = 60, seed: int = 0):
             yield from QA.pair_batches(corpus, tok, cfg.max_len, 64, seed=ep)
             ep += 1
 
-    tr.run(stream(), max_steps=train_steps, log_every=0)
+    last = tr.run(stream(), max_steps=train_steps, log_every=0)
+    if not np.isfinite(last.get("loss", 0.0)):
+        raise FloatingPointError(f"{cfg.name}: training loss "
+                                 f"{last['loss']} after {tr.step} steps")
     eval_pairs = [p for i, p in enumerate(corpus.pairs) if i % 10 == 0]
     return cfg, tr.params, corpus, tok, index, eval_pairs
 

@@ -243,11 +243,6 @@ def moe_apply_a2a(p: Dict, x: jnp.ndarray, cfg: LMConfig, mesh,
                 jax.tree.map(lambda _: P(None, None), shared),
                 P(dpa, axis, None))                 # x: batch x seq(SP)
     out_specs = (P(dpa, axis, None), P())
-    if hasattr(jax, "shard_map"):  # jax >= 0.6
-        fn = jax.shard_map(block, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(block, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(block, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(p["router"], p["w_gate"], p["w_up"], p["w_down"], shared, x)

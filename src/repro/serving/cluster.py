@@ -38,6 +38,14 @@ from repro.serving.stats import LatencyTracker
 POLICIES = ("round_robin", "least_outstanding", "p2c")
 
 
+def _replica_device(i: int):
+    """Replica *i*'s device: one replica per chip, wrapping when there are
+    more replicas than devices."""
+    import jax
+    devices = jax.devices()
+    return devices[i % len(devices)]
+
+
 class Replica:
     """One scorer + its micro-batching worker + counters.
 
@@ -111,12 +119,14 @@ class ReplicaPool:
     def build(cls, backend: str, params, cfg, tokenizer: HashingTokenizer,
               idf: Dict[str, float], n_replicas: int = 2,
               buckets: Sequence[int] = (1, 8, 64), **kw) -> "ReplicaPool":
-        """Convenience: N fresh scorer instances of one backend. Pools
-        built this way remember how (backend/cfg/buckets), which is what
+        """Convenience: N fresh scorer instances of one backend, replica
+        *i* on device *i* mod the device count. Pools built this way
+        remember how (backend/cfg/buckets), which is what
         ``swap_version`` needs to rebuild replicas on a new version."""
         from repro.core import backends as BK
-        scorers = [BK.make_scorer(backend, params, cfg, buckets=buckets)
-                   for _ in range(n_replicas)]
+        scorers = [BK.make_scorer(backend, params, cfg, buckets=buckets,
+                                  device=_replica_device(i))
+                   for i in range(n_replicas)]
         pool = cls(scorers, tokenizer, idf, cfg.max_len, **kw)
         pool._build_info = (backend, cfg, tuple(buckets))
         pool._params_template = params
@@ -291,9 +301,10 @@ class ReplicaPool:
             params = registry.load_params(vid,
                                           template=self._params_template)
             t0 = time.perf_counter()
-            for rep in self.replicas:
+            for i, rep in enumerate(self.replicas):
                 scorer = BK.make_scorer(backend, params, cfg,
-                                        buckets=buckets)
+                                        buckets=buckets,
+                                        device=_replica_device(i))
                 self._swap_replica(rep, scorer, drain_timeout_s)
             self._params_template = params
             self.model_version = vid

@@ -10,6 +10,9 @@ the neural network as a service" over Thrift — scales by running separate
                   worker PROCESSES (each its own interpreter, jit cache
                   and admission controller), watches them, respawns
                   crashes, and drains workers gracefully for restarts.
+                  On a TPU host each worker gets a chip of its own
+                  (``chip_env``) and N may not exceed the chips; the
+                  supervisor itself never loads JAX's backend.
   FabricWorker  — one worker process: the ``subprocess.Popen`` handle, a
                   stdout reader thread that captures the flushed
                   ``FABRIC_READY host port`` discovery line (workers bind
@@ -48,6 +51,7 @@ Lifecycle (mirrors a compose-style deployment: up / ps / drain / down):
 from __future__ import annotations
 
 import collections
+import os
 import subprocess
 import sys
 import threading
@@ -63,6 +67,32 @@ from repro.serving.hedge import HedgedTransport
 #: ``FABRIC_READY <host> <port>``. Workers bind port 0, so the supervisor
 #: can only learn the address from this line.
 READY_PREFIX = "FABRIC_READY"
+
+
+def host_tpu_chips(dev_root: str = "/dev") -> int:
+    """TPU chips this host lets its processes open — ``accel<N>`` device
+    files (TPU v4) or numbered VFIO groups (v5e and later) — counted
+    without loading JAX's backend, which would claim them. (The PCI bus
+    can list chips that are not passed through to this host.)"""
+    root = Path(dev_root)
+    return (len(list(root.glob("accel[0-9]*")))
+            + sum(p.name.isdigit() for p in root.glob("vfio/*")))
+
+
+def chip_env(slot: int, n_chips: int) -> Dict[str, str]:
+    """Environment that gives fabric worker ``slot`` chip ``slot`` of the
+    host as a 1x1 slice of its own; empty on a host without chips. On a
+    v5e 2x2 host, four processes with these variables each opened their
+    own chip at the same time, with libtpu's one-process lock left on."""
+    if n_chips == 0:
+        return {}
+    if not 0 <= slot < n_chips:
+        raise ValueError(f"worker slot {slot} has no chip: the host has "
+                         f"{n_chips}")
+    return {"TPU_VISIBLE_CHIPS": str(slot),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(8476 + slot)}
 
 
 def _src_root() -> str:
@@ -83,8 +113,12 @@ class FabricWorker:
     def __init__(self, slot: int, backend: str = "numpy",
                  train_steps: int = 1, server: str = "threadpool",
                  workers: int = 8, max_queue: int = 512,
-                 extra_args: Sequence[str] = (), tail_lines: int = 40):
+                 extra_args: Sequence[str] = (), tail_lines: int = 40,
+                 env: Optional[Dict[str, str]] = None):
         self.slot = slot
+        #: Variables set on top of the supervisor's environment (the
+        #: worker's chip, see ``chip_env``).
+        self.env = dict(env or {})
         self.backend = backend
         self.train_steps = train_steps
         self.server = server
@@ -116,8 +150,7 @@ class FabricWorker:
 
     def spawn(self) -> None:
         """Start the process (non-blocking; pair with ``wait_ready``)."""
-        import os
-        env = dict(os.environ)
+        env = dict(os.environ, **self.env)
         env["PYTHONPATH"] = _src_root() + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
         self.address = None
@@ -388,14 +421,22 @@ class Fabric:
                  probe_interval_s: float = 0.05,
                  hedge_s: Optional[float] = None,
                  supervise: bool = True,
-                 extra_args: Sequence[str] = ()):
+                 extra_args: Sequence[str] = (),
+                 n_chips: Optional[int] = None):
         if n_workers < 1:
             raise ValueError("Fabric needs at least one worker")
+        # One worker per chip: a chip belongs to one process at a time.
+        n_chips = host_tpu_chips() if n_chips is None else n_chips
+        if n_chips and n_workers > n_chips:
+            raise ValueError(f"{n_workers} fabric workers asked for, but "
+                             f"this host has {n_chips} TPU chips "
+                             f"(one worker per chip)")
         self.workers = [FabricWorker(i, backend=backend,
                                      train_steps=train_steps, server=server,
                                      workers=worker_threads,
                                      max_queue=max_queue,
-                                     extra_args=extra_args)
+                                     extra_args=extra_args,
+                                     env=chip_env(i, n_chips))
                         for i in range(n_workers)]
         self.spawn_timeout_s = spawn_timeout_s
         self.probe_interval_s = probe_interval_s

@@ -315,3 +315,58 @@ def test_four_replica_pool_no_spurious_late_sheds(world):
         assert sheds_serial > 0                      # the old behavior
     finally:
         pool.stop()
+
+
+# ------------------------------------------------------- device placement
+
+_PLACEMENT_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import dataclasses
+import jax
+from repro.core.plan import PlanContext, plan
+from repro.launch.serve import canonical_pipeline
+from repro.launch.world import build_world
+from repro.serving.cluster import ReplicaPool
+
+cfg, params, corpus, tok, index, _ = build_world(train_steps=2)
+queries = corpus.questions[:24]
+ctx = PlanContext.from_world(cfg, params, corpus, tok, index,
+                             buckets=(1, 8, 64))
+rankings = {}
+for n in (1, 4):
+    with ReplicaPool.build("BACKEND", params, cfg, tok, corpus.idf,
+                           n_replicas=n, buckets=(1, 8, 64),
+                           policy="round_robin") as pool:
+        scorers = [r.batcher.scorer for r in pool.replicas]
+        assert [s.device for s in scorers] == jax.devices()[:n]
+        for s in scorers:
+            assert {d for leaf in jax.tree.leaves(s.params)
+                    for d in leaf.devices()} == {s.device}
+        with plan(canonical_pipeline("BACKEND"), "remote",
+                  dataclasses.replace(ctx, remote=pool)) as p:
+            rankings[n] = [[(c.doc_id, c.sent_id, c.score) for c in cands]
+                           for cands, _ in (p.run(q) for q in queries)]
+        rows = [pool.stats()[f"replica{i}_rows_scored"] for i in range(n)]
+        assert all(r > 0 for r in rows), rows
+assert rankings[4] == rankings[1]
+print("PLACEMENT_OK")
+"""
+
+
+@pytest.mark.parametrize("backend", ["jit", "aot"])
+def test_pool_puts_each_replica_on_its_own_device(backend):
+    """4 replicas on 4 (virtual) devices: each replica's params live on its
+    own device, every replica scores, and the rankings equal one
+    replica's. A subprocess, because the device count must be set before
+    jax initializes."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_SCRIPT.replace("BACKEND", backend)],
+        env=dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+                 JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300, cwd=root)
+    assert "PLACEMENT_OK" in out.stdout, out.stdout + out.stderr

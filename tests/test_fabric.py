@@ -12,7 +12,7 @@ import time
 import pytest
 
 from repro.serving.fabric import (Fabric, FabricWorker, HealthRouter,
-                                  WorkerEndpoint)
+                                  WorkerEndpoint, chip_env, host_tpu_chips)
 
 
 @pytest.fixture(scope="module")
@@ -164,6 +164,55 @@ def test_worker_command_shape():
     assert cmd[cmd.index("--train-steps") + 1] == "7"
     assert cmd[cmd.index("--port") + 1] == "0"
     assert "-u" in cmd                      # unbuffered: READY must flush
+
+
+def test_each_worker_gets_its_own_chip(monkeypatch):
+    fab = Fabric(n_workers=3, supervise=False, n_chips=4)
+    assert [w.env for w in fab.workers] == [chip_env(i, 4) for i in range(3)]
+    assert [w.env["TPU_VISIBLE_CHIPS"] for w in fab.workers] == ["0", "1",
+                                                                 "2"]
+    assert all(w.env["TPU_PROCESS_BOUNDS"] == "1,1,1" for w in fab.workers)
+    assert len({w.env["TPU_PROCESS_PORT"] for w in fab.workers}) == 3
+    # The spawned process sees the slot's variables over the parent's.
+    import repro.serving.fabric as FB
+    seen = {}
+
+    class _Popen:
+        def __init__(self, cmd, env, **kw):
+            seen.update(env)
+            self.stdout = iter(())
+            self.pid = 0
+
+        def poll(self):
+            return 0
+
+    monkeypatch.setattr(FB.subprocess, "Popen", _Popen)
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0,1,2,3")
+    fab.workers[2].spawn()
+    assert seen["TPU_VISIBLE_CHIPS"] == "2"
+    assert seen["PYTHONPATH"].startswith(FB._src_root())
+
+
+def test_no_chip_environment_without_chips():
+    fab = Fabric(n_workers=2, supervise=False, n_chips=0)
+    assert [w.env for w in fab.workers] == [{}, {}]
+
+
+def test_more_workers_than_chips_is_refused_before_spawning():
+    with pytest.raises(ValueError, match="5 fabric workers .* 4 TPU chips"):
+        Fabric(n_workers=5, supervise=False, n_chips=4)
+    with pytest.raises(ValueError, match="slot 4 has no chip"):
+        chip_env(4, 4)
+
+
+def test_host_tpu_chips_counts_device_files(tmp_path):
+    assert host_tpu_chips(str(tmp_path)) == 0
+    (tmp_path / "vfio").mkdir()
+    for name in ("vfio/vfio", "vfio/0", "vfio/1"):   # vfio/vfio: control
+        (tmp_path / name).touch()
+    assert host_tpu_chips(str(tmp_path)) == 2
+    (tmp_path / "accel0").touch()
+    assert host_tpu_chips(str(tmp_path)) == 3
 
 
 def test_health_router_prefers_less_loaded_worker():

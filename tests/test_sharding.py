@@ -10,9 +10,8 @@ from repro.distributed import sharding as SH
 from repro.roofline.analysis import model_bytes, model_flops
 from repro.roofline.hlo_parse import shape_bytes, split_computations
 
-# jax >= 0.4.35 takes a single ((name, size), ...) shape tuple
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _lm_tree():

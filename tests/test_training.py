@@ -140,7 +140,6 @@ def test_compression_error_feedback_unbiased_over_time():
 def test_compressed_psum_matches_mean(monkeypatch):
     """shard_map int8 psum ≈ the fp32 mean within quantization error."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     devs = np.array(jax.devices()[:1])
     mesh = Mesh(devs, ("d",))
     g = {"w": jnp.linspace(-1, 1, 8)[None, :]}
@@ -149,7 +148,7 @@ def test_compressed_psum_matches_mean(monkeypatch):
     def f(g, e):
         return C.compressed_psum(g, e, "d")
 
-    out, _ = shard_map(f, mesh=mesh, in_specs=(P("d"), P("d")),
+    out, _ = jax.shard_map(f, mesh=mesh, in_specs=(P("d"), P("d")),
                        out_specs=(P("d"), P("d")))(g, err)
     np.testing.assert_allclose(np.asarray(out["w"][0]),
                                np.asarray(g["w"][0]), atol=2e-2)
