@@ -10,7 +10,7 @@ the neural network as a service" over Thrift — scales by running separate
                   worker PROCESSES (each its own interpreter, jit cache
                   and admission controller), watches them, respawns
                   crashes, and drains workers gracefully for restarts.
-                  On a TPU host each worker gets a chip of its own
+                  Where workers open a TPU each gets a chip of its own
                   (``chip_env``) and N may not exceed the chips; the
                   supervisor itself never loads JAX's backend.
   FabricWorker  — one worker process: the ``subprocess.Popen`` handle, a
@@ -69,21 +69,38 @@ from repro.serving.hedge import HedgedTransport
 READY_PREFIX = "FABRIC_READY"
 
 
-def host_tpu_chips(dev_root: str = "/dev") -> int:
-    """TPU chips this host lets its processes open — ``accel<N>`` device
-    files (TPU v4) or numbered VFIO groups (v5e and later) — counted
-    without loading JAX's backend, which would claim them. (The PCI bus
-    can list chips that are not passed through to this host.)"""
-    root = Path(dev_root)
-    return (len(list(root.glob("accel[0-9]*")))
-            + sum(p.name.isdigit() for p in root.glob("vfio/*")))
+#: PCI vendor id of Google, the maker of the TPU chips.
+GOOGLE_PCI_VENDOR = "0x1ae0"
+_DEV = Path("/dev")
+_IOMMU_GROUPS = Path("/sys/kernel/iommu_groups")
+
+
+def host_tpu_chips() -> int:
+    """TPU chips this host lets its processes open, counted from device
+    files without loading JAX's backend, which would claim them:
+    ``accel<N>`` files (TPU v4), and numbered VFIO groups (v5e and later)
+    whose PCI devices are Google's. (The PCI bus alone can list chips that
+    are not passed through to this host.)"""
+    def google(group: str) -> bool:
+        return any((d / "vendor").read_text().strip() == GOOGLE_PCI_VENDOR
+                   for d in (_IOMMU_GROUPS / group / "devices").glob("*"))
+    return (len(list(_DEV.glob("accel[0-9]*")))
+            + sum(google(g.name) for g in _DEV.glob("vfio/[0-9]*")))
+
+
+def opens_tpu(env) -> bool:
+    """Whether JAX, started with environment ``env``, opens a TPU: unless
+    ``JAX_PLATFORMS`` names platforms, tpu not among them."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    return not platforms or "tpu" in platforms.lower().split(",")
 
 
 def chip_env(slot: int, n_chips: int) -> Dict[str, str]:
     """Environment that gives fabric worker ``slot`` chip ``slot`` of the
     host as a 1x1 slice of its own; empty on a host without chips. On a
-    v5e 2x2 host, four processes with these variables each opened their
-    own chip at the same time, with libtpu's one-process lock left on."""
+    v5e 2x2 host, four processes with these variables held
+    ``/dev/vfio/0`` to ``/dev/vfio/3``, one each, at the same time, with
+    libtpu's one-process lock left on."""
     if n_chips == 0:
         return {}
     if not 0 <= slot < n_chips:
@@ -91,8 +108,7 @@ def chip_env(slot: int, n_chips: int) -> Dict[str, str]:
                          f"{n_chips}")
     return {"TPU_VISIBLE_CHIPS": str(slot),
             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
-            "TPU_PROCESS_BOUNDS": "1,1,1",
-            "TPU_PROCESS_PORT": str(8476 + slot)}
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
 
 
 def _src_root() -> str:
@@ -421,12 +437,12 @@ class Fabric:
                  probe_interval_s: float = 0.05,
                  hedge_s: Optional[float] = None,
                  supervise: bool = True,
-                 extra_args: Sequence[str] = (),
-                 n_chips: Optional[int] = None):
+                 extra_args: Sequence[str] = ()):
         if n_workers < 1:
             raise ValueError("Fabric needs at least one worker")
         # One worker per chip: a chip belongs to one process at a time.
-        n_chips = host_tpu_chips() if n_chips is None else n_chips
+        # Workers held off the TPU (JAX_PLATFORMS=cpu) need no chip.
+        n_chips = host_tpu_chips() if opens_tpu(os.environ) else 0
         if n_chips and n_workers > n_chips:
             raise ValueError(f"{n_workers} fabric workers asked for, but "
                              f"this host has {n_chips} TPU chips "

@@ -12,7 +12,8 @@ import time
 import pytest
 
 from repro.serving.fabric import (Fabric, FabricWorker, HealthRouter,
-                                  WorkerEndpoint, chip_env, host_tpu_chips)
+                                  WorkerEndpoint, chip_env, host_tpu_chips,
+                                  opens_tpu)
 
 
 @pytest.fixture(scope="module")
@@ -166,13 +167,24 @@ def test_worker_command_shape():
     assert "-u" in cmd                      # unbuffered: READY must flush
 
 
-def test_each_worker_gets_its_own_chip(monkeypatch):
-    fab = Fabric(n_workers=3, supervise=False, n_chips=4)
+@pytest.fixture
+def tpu_host(monkeypatch):
+    """A host with ``n`` TPU chips whose workers open the TPU."""
+    import repro.serving.fabric as FB
+
+    def make(n):
+        monkeypatch.setattr(FB, "host_tpu_chips", lambda: n)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    return make
+
+
+def test_each_worker_gets_its_own_chip(monkeypatch, tpu_host):
+    tpu_host(4)
+    fab = Fabric(n_workers=3, supervise=False)
     assert [w.env for w in fab.workers] == [chip_env(i, 4) for i in range(3)]
     assert [w.env["TPU_VISIBLE_CHIPS"] for w in fab.workers] == ["0", "1",
                                                                  "2"]
     assert all(w.env["TPU_PROCESS_BOUNDS"] == "1,1,1" for w in fab.workers)
-    assert len({w.env["TPU_PROCESS_PORT"] for w in fab.workers}) == 3
     # The spawned process sees the slot's variables over the parent's.
     import repro.serving.fabric as FB
     seen = {}
@@ -193,26 +205,58 @@ def test_each_worker_gets_its_own_chip(monkeypatch):
     assert seen["PYTHONPATH"].startswith(FB._src_root())
 
 
-def test_no_chip_environment_without_chips():
-    fab = Fabric(n_workers=2, supervise=False, n_chips=0)
+def test_no_chip_environment_without_chips(tpu_host):
+    tpu_host(0)
+    fab = Fabric(n_workers=2, supervise=False)
     assert [w.env for w in fab.workers] == [{}, {}]
 
 
-def test_more_workers_than_chips_is_refused_before_spawning():
+@pytest.mark.parametrize("platforms", ["cpu", "CPU", "cuda,cpu"])
+def test_workers_off_the_tpu_need_no_chip(monkeypatch, tpu_host, platforms):
+    # A one-chip host still runs a 2-worker fabric held off the TPU.
+    tpu_host(1)
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    fab = Fabric(n_workers=2, supervise=False)
+    assert [w.env for w in fab.workers] == [{}, {}]
+
+
+@pytest.mark.parametrize("env,opens", [
+    ({}, True), ({"JAX_PLATFORMS": ""}, True),
+    ({"JAX_PLATFORMS": "tpu"}, True), ({"JAX_PLATFORMS": "tpu,cpu"}, True),
+    ({"JAX_PLATFORMS": "cpu"}, False), ({"JAX_PLATFORMS": "cpu,cuda"}, False),
+])
+def test_opens_tpu(env, opens):
+    assert opens_tpu(env) is opens
+
+
+def test_more_workers_than_chips_is_refused_before_spawning(tpu_host):
+    tpu_host(4)
     with pytest.raises(ValueError, match="5 fabric workers .* 4 TPU chips"):
-        Fabric(n_workers=5, supervise=False, n_chips=4)
+        Fabric(n_workers=5, supervise=False)
     with pytest.raises(ValueError, match="slot 4 has no chip"):
         chip_env(4, 4)
 
 
-def test_host_tpu_chips_counts_device_files(tmp_path):
-    assert host_tpu_chips(str(tmp_path)) == 0
-    (tmp_path / "vfio").mkdir()
-    for name in ("vfio/vfio", "vfio/0", "vfio/1"):   # vfio/vfio: control
-        (tmp_path / name).touch()
-    assert host_tpu_chips(str(tmp_path)) == 2
-    (tmp_path / "accel0").touch()
-    assert host_tpu_chips(str(tmp_path)) == 3
+def test_host_tpu_chips_counts_device_files(monkeypatch, tmp_path):
+    import repro.serving.fabric as FB
+    dev, groups = tmp_path / "dev", tmp_path / "iommu_groups"
+    monkeypatch.setattr(FB, "_DEV", dev)
+    monkeypatch.setattr(FB, "_IOMMU_GROUPS", groups)
+    assert host_tpu_chips() == 0
+    (dev / "vfio").mkdir(parents=True)
+    for group, vendor in (("0", FB.GOOGLE_PCI_VENDOR),
+                          ("1", FB.GOOGLE_PCI_VENDOR),
+                          ("2", "0x10de"),               # not a TPU
+                          ("3", FB.GOOGLE_PCI_VENDOR)):  # not passed through
+        pci = groups / group / "devices" / f"0000:00:0{group}.0"
+        pci.mkdir(parents=True)
+        (pci / "vendor").write_text(vendor + "\n")
+        if group != "3":
+            (dev / "vfio" / group).touch()
+    (dev / "vfio" / "vfio").touch()                      # the control file
+    assert host_tpu_chips() == 2
+    (dev / "accel0").touch()
+    assert host_tpu_chips() == 3
 
 
 def test_health_router_prefers_less_loaded_worker():

@@ -1,6 +1,7 @@
 """Entry-point plumbing: config names, the compile-cache rule, and the
 chip smoke's refusal to run anywhere but on a TPU."""
 import json
+import math
 import os
 import subprocess
 import sys
@@ -74,3 +75,43 @@ def test_chip_smoke_refuses_the_cpu():
     for line in out.stdout.splitlines():
         with pytest.raises(ValueError):
             json.loads(line)
+
+
+@pytest.fixture(scope="module")
+def chip_smoke():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(ROOT, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _shifted(p, dlogit):
+    return float(1 / (1 + (1 - p) / p * math.exp(-dlogit)))
+
+
+@pytest.mark.parametrize("shift,caught", [(0.0, False), (0.05, False),
+                                          (0.3, True), (-0.3, True)])
+def test_chip_smoke_compares_saturated_scores_as_logits(chip_smoke, shift,
+                                                        caught):
+    # Near-saturated scores: a logit shift of 0.3 moves P by under 1e-2,
+    # which a check on P alone would let through.
+    want = {"q": [(0, i, p) for i, p in enumerate((0.999, 0.99, 0.02,
+                                                   0.001))]}
+    got = {"q": [(d, s, _shifted(p, shift)) for d, s, p in want["q"]]}
+    assert max(abs(g[2] - w[2]) for g, w in zip(got["q"], want["q"])) < 1e-2
+    if caught:
+        with pytest.raises(SystemExit, match="FAILED"):
+            chip_smoke.check_rankings("t", got, want, 4, 0.1)
+    else:
+        identical, _, max_dl = chip_smoke.check_rankings("t", got, want, 4,
+                                                         0.1)
+        assert identical == 1 and max_dl == pytest.approx(shift, abs=1e-6)
+
+
+def test_chip_smoke_logit_clips_saturated_scores(chip_smoke):
+    lo, hi = chip_smoke.SCORE_CLIP, 1 - chip_smoke.SCORE_CLIP
+    assert chip_smoke.logit([0.0, 1.0]) == pytest.approx(
+        chip_smoke.logit([lo, hi]))
+    assert chip_smoke.logit(0.5) == pytest.approx(0.0)
