@@ -407,7 +407,7 @@ def run(world=None, qps_levels: Sequence[float] = (100.0, 300.0),
     # Warm every replica at every coalescing bucket so runtime jit
     # compilation doesn't masquerade as tail latency in the sweep.
     for bucket in (1, 8, 64):
-        q_tok, a_tok, feats = pool._featurize_batch(reqs[:bucket])
+        q_tok, a_tok, feats = pool.features.featurize_many(reqs[:bucket])
         for rep in pool.replicas:
             rep.batcher.submit_many(q_tok, a_tok, feats).result()
     admission = AdmissionController(max_queue_rows=256)

@@ -39,28 +39,25 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--table", default="all",
                     choices=["all", "1", "2", "e2e", "pipeline_plans",
-                             "loadgen", "fabric", "roofline", "trace",
-                             "rollout", "lint"])
+                             "loadgen", "fabric", "roofline", "rollout",
+                             "lint"])
     ap.add_argument("--processes", default="1,2,4", metavar="N,N,...",
                     help="worker-process counts for --table fabric")
     ap.add_argument("--naive", action="store_true",
                     help="include the naive per-filter conv condition")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the result rows as a JSON list")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="for --table trace: also export the collected "
-                         "spans as Chrome trace-event JSON (Perfetto)")
     args = ap.parse_args()
 
     from benchmarks import (e2e_pipeline, loadgen, pipeline_plans,
                             rollout_bench, roofline_table,
-                            table1_feedforward, table2_service, trace_table)
+                            table1_feedforward, table2_service)
     from benchmarks.common import build_world
 
     rows = []
     world = None
     if args.table in ("all", "1", "2", "e2e", "pipeline_plans", "loadgen",
-                      "trace", "rollout"):
+                      "rollout"):
         world = build_world()
     if args.table in ("all", "1"):
         rows += table1_feedforward.run(batch=1, world=world, naive=args.naive)
@@ -91,10 +88,6 @@ def main() -> None:
         # Not in "all": it drives a live 2-replica pool with closed-loop
         # client threads for a couple of seconds per condition.
         rows += rollout_bench.run(world=world)
-    if args.table == "trace":
-        # Not in "all": it stands up its own served pipeline and toggles
-        # the process-wide tracer for the overhead measurement.
-        rows += trace_table.run(world=world, trace_out=args.trace_out)
 
     print("name,us_per_call,derived")
     for r in rows:

@@ -75,16 +75,6 @@ def main():
         print(f"\n== span tree: last query ({queries[-1]!r}) ==")
         print(telemetry.format_span_tree(spans, trace_id=last_trace))
 
-        print("\n== per-stage breakdown over all "
-              f"{len(queries)} queries ==")
-        agg = telemetry.stage_breakdown(spans)
-        width = max(len(n) for n in agg)
-        for name in sorted(agg, key=lambda n: -agg[n]["total_ms"]):
-            a = agg[name]
-            print(f"  {name:<{width}}  n={int(a['count']):4d}  "
-                  f"mean={a['mean_ms']:8.3f}ms  "
-                  f"total={a['total_ms']:8.1f}ms")
-
         print("\n== metrics registry snapshot (MSG_STATS payload) ==")
         snap = telemetry.get_registry().snapshot()
         for key in sorted(snap):

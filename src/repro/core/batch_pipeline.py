@@ -28,7 +28,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.pipeline import (Candidate, MultiStageRanker, RerankStage,
-                                 Stage, StageResult)
+                                 RetrievalStage, Stage, StageResult)
 from repro.data.featurize import FeaturizationCache
 
 QueryResult = Tuple[List[Candidate], List[StageResult]]
@@ -85,7 +85,8 @@ class BatchedMultiStageRanker:
             # One span per stage for the whole coalesced batch (the work IS
             # batch-wide); per-query amortized time stays in the StageResult
             # trace so the two views agree on totals.
-            with tracer.span(f"stage.{stage.name}", queries=len(queries)):
+            with tracer.span(f"stage.{stage.name}", queries=len(queries),
+                             cpu=isinstance(stage, RetrievalStage)):
                 if isinstance(stage, RerankStage):
                     self._run_rerank_coalesced(stage, queries, states,
                                                traces)
@@ -111,32 +112,15 @@ class BatchedMultiStageRanker:
                               queries: Sequence[str],
                               states: List[Optional[List[Candidate]]],
                               traces: List[List[StageResult]]) -> None:
-        from repro.serving import telemetry
         t0 = time.perf_counter()
         cache = self._cache_for(stage)
         # gather the cross-query work list; queries with no candidates keep
         # the sequential contract (an empty StageResult, no scorer row)
         active = [i for i, c in enumerate(states) if c]
-        segments: List[Tuple[int, int]] = []   # (query index, n candidates)
-        with telemetry.get_tracer().span("featurize") as feat_span:
-            before = cache.stats()
-            q_rows, a_rows, pairs = [], [], []
-            for i in active:
-                cands = states[i]
-                q_row = cache.query_row(queries[i])   # encoded ONCE per query
-                for c in cands:
-                    q_rows.append(q_row)
-                    a_rows.append(cache.answer_row(c.text))
-                    pairs.append((queries[i], c.text))
-                segments.append((i, len(cands)))
-            feats = (cache.pair_feats_many(pairs) if q_rows
-                     else np.zeros((0, 4), np.float32))
-            after = cache.stats()
-            feat_span.set_attr("rows", len(pairs))
-            feat_span.set_attr("hits", int(after["feat_cache_hits"]
-                                           - before["feat_cache_hits"]))
-            feat_span.set_attr("misses", int(after["feat_cache_misses"]
-                                             - before["feat_cache_misses"]))
+        segments: List[Tuple[int, int]] = [(i, len(states[i]))
+                                           for i in active]
+        q_rows, a_rows, feats = cache.featurize_grouped(
+            [(queries[i], [c.text for c in states[i]]) for i in active])
 
         if q_rows:
             scores = stage.scorer(np.stack(q_rows), np.stack(a_rows), feats)

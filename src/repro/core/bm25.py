@@ -8,12 +8,17 @@ gather/segment substrate the GNN and recsys layers use.
 
 Postings for a query are padded to a fixed budget so the scoring function is
 jit-stable across queries (one compiled entry per budget bucket).
+
+``retrieve``/``retrieve_many`` split their time into two spans: ``bm25.gather``
+(reading the query terms, the postings gather, padding and stacking) and
+``bm25.score`` (the call into the jitted program, the ``doc_len`` and
+postings transfer, and the read-back of top-h).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,7 +70,7 @@ def build_index(docs_tokens: Sequence[Sequence[int]], vocab_size: int) -> BM25In
                      float(doc_len.mean() or 1.0), n_docs)
 
 
-def gather_query_postings(index: BM25Index, query_terms: Sequence[int],
+def gather_query_postings(index: BM25Index, query_terms: Iterable[int],
                           budget: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Host-side ragged gather -> fixed-size (docs, tf, idf_per_posting)."""
     docs, tfs, idfs = [], [], []
@@ -101,14 +106,19 @@ def _score_postings(post_docs, post_tf, post_idf, doc_len, avg_dl, h):
     return jax.lax.top_k(scores, h)
 
 
-def retrieve(index: BM25Index, query_terms: Sequence[int], h: int,
+def retrieve(index: BM25Index, query_terms: Iterable[int], h: int,
              budget: int = 16384) -> Tuple[np.ndarray, np.ndarray]:
-    """Top-h (scores, doc_ids) for a query."""
-    docs, tfs, idfs = gather_query_postings(index, query_terms, budget)
-    scores, ids = _score_postings(docs, tfs, idfs,
-                                  jnp.asarray(index.doc_len),
-                                  index.avg_dl, h)
-    return np.asarray(scores), np.asarray(ids)
+    """Top-h (scores, doc_ids) for a query. ``query_terms`` is read inside
+    the ``bm25.gather`` span, so lazy terms put their encoding there."""
+    from repro.serving import telemetry
+    tracer = telemetry.get_tracer()
+    with tracer.span("bm25.gather"):
+        docs, tfs, idfs = gather_query_postings(index, query_terms, budget)
+    with tracer.span("bm25.score"):
+        scores, ids = _score_postings(docs, tfs, idfs,
+                                      jnp.asarray(index.doc_len),
+                                      index.avg_dl, h)
+        return np.asarray(scores), np.asarray(ids)
 
 
 @functools.partial(jax.jit, static_argnames=("h",))
@@ -132,28 +142,35 @@ def _pad_bucket(n: int, lo: int = 256) -> int:
     return b
 
 
-def retrieve_many(index: BM25Index, queries_terms: Sequence[Sequence[int]],
+def retrieve_many(index: BM25Index, queries_terms: Sequence[Iterable[int]],
                   h: int, budget: int = 16384
                   ) -> List[Tuple[np.ndarray, np.ndarray]]:
     """Batched ``retrieve``: same per-query (scores, doc_ids), one padded
     (Q, P) scoring call. Both dims are bucketed to powers of two so jit
     entries are shared across batch sizes (all-zero padding rows/columns
-    contribute nothing and padded-query results are discarded)."""
+    contribute nothing and padded-query results are discarded). Each
+    query's terms are read inside the ``bm25.gather`` span, so lazy terms
+    put their encoding there."""
     if not queries_terms:
         return []
-    gathered = [gather_query_postings(index, t, budget) for t in queries_terms]
-    # gather pads each to `budget`; trim to the batch max, then re-bucket
-    # (real postings always have tf > 0, padding is all-zero)
-    nnz = [int(np.count_nonzero(g[1])) for g in gathered]
-    p = min(budget, _pad_bucket(max(max(nnz), 1)))
-    qb = _pad_bucket(len(gathered), lo=8)
-    pad_rows = [(np.zeros((p,), np.int32), np.zeros((p,), np.float32),
-                 np.zeros((p,), np.float32))] * (qb - len(gathered))
-    docs = np.stack([g[0][:p] for g in gathered + pad_rows])
-    tfs = np.stack([g[1][:p] for g in gathered + pad_rows])
-    idfs = np.stack([g[2][:p] for g in gathered + pad_rows])
-    scores, ids = _score_postings_many(docs, tfs, idfs,
-                                       jnp.asarray(index.doc_len),
-                                       index.avg_dl, h)
-    scores, ids = np.asarray(scores), np.asarray(ids)
+    from repro.serving import telemetry
+    tracer = telemetry.get_tracer()
+    with tracer.span("bm25.gather"):
+        gathered = [gather_query_postings(index, t, budget)
+                    for t in queries_terms]
+        # gather pads each to `budget`; trim to the batch max, then
+        # re-bucket (real postings always have tf > 0, padding is all-zero)
+        nnz = [int(np.count_nonzero(g[1])) for g in gathered]
+        p = min(budget, _pad_bucket(max(max(nnz), 1)))
+        qb = _pad_bucket(len(gathered), lo=8)
+        pad_rows = [(np.zeros((p,), np.int32), np.zeros((p,), np.float32),
+                     np.zeros((p,), np.float32))] * (qb - len(gathered))
+        docs = np.stack([g[0][:p] for g in gathered + pad_rows])
+        tfs = np.stack([g[1][:p] for g in gathered + pad_rows])
+        idfs = np.stack([g[2][:p] for g in gathered + pad_rows])
+    with tracer.span("bm25.score"):
+        scores, ids = _score_postings_many(docs, tfs, idfs,
+                                           jnp.asarray(index.doc_len),
+                                           index.avg_dl, h)
+        scores, ids = np.asarray(scores), np.asarray(ids)
     return [(scores[i], ids[i]) for i in range(len(gathered))]

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -70,19 +70,29 @@ class RetrievalStage(Stage):
                 out.append(Candidate(int(di), si, sent, float(s)))
         return out
 
+    def _terms(self, query: str) -> Iterator[int]:
+        """The query's term ids, encoded when first read: inside bm25's
+        ``bm25.gather`` span, which then covers query encoding."""
+        yield from self.tok.encode(query)
+
     def run(self, query, candidates=None) -> List[Candidate]:
-        terms = self.tok.encode(query)
-        scores, doc_ids = bm25_lib.retrieve(self.index, terms, self.h)
-        return self._segment(scores, doc_ids)
+        from repro.serving import telemetry
+        scores, doc_ids = bm25_lib.retrieve(self.index, self._terms(query),
+                                            self.h)
+        with telemetry.get_tracer().span("bm25.segment"):
+            return self._segment(scores, doc_ids)
 
     def run_batch(self, queries: Sequence[str],
                   states=None) -> List[List[Candidate]]:
         """Per-query retrieval, but one coalesced (Q, P) BM25 scoring call
         (identical per-query results to ``run``)."""
+        from repro.serving import telemetry
         hits = bm25_lib.retrieve_many(self.index,
-                                      [self.tok.encode(q) for q in queries],
+                                      [self._terms(q) for q in queries],
                                       self.h)
-        return [self._segment(scores, doc_ids) for scores, doc_ids in hits]
+        with telemetry.get_tracer().span("bm25.segment"):
+            return [self._segment(scores, doc_ids)
+                    for scores, doc_ids in hits]
 
 
 class RerankStage(Stage):
@@ -172,7 +182,8 @@ class MultiStageRanker:
         trace = []
         for stage in self.stages:
             t0 = time.perf_counter()
-            with tracer.span(f"stage.{stage.name}") as sp:
+            with tracer.span(f"stage.{stage.name}",
+                             cpu=isinstance(stage, RetrievalStage)) as sp:
                 candidates = stage.run(query, candidates)
                 sp.set_attr("out", len(candidates or ()))
             trace.append(StageResult(stage.name, candidates,

@@ -580,6 +580,10 @@ class Client:
     fabric's control-plane probe connections, which would otherwise flood
     the span ring at probe frequency).
 
+    One RPC at a time crosses the socket: callers on other threads (the
+    fabric's probe thread and its supervisor share a control connection)
+    wait their turn, so replies never cross.
+
     Data-plane methods take either deadline form: the wire-native
     *relative* budget (``deadline_s``) or the serving stack's *absolute*
     perf-counter deadline (``deadline_abs``, converted to the remaining
@@ -602,6 +606,7 @@ class Client:
         self.trace = trace
         self.shed_retries = 0
         self._endpoint = f"{address[0]}:{address[1]}"
+        self._io = threading.Lock()     # one framed RPC on the socket
         self._sock = self._connect()
 
     def _span(self, method: str):
@@ -653,27 +658,28 @@ class Client:
         resent to a server that would score-then-shed it as expired.
         """
         t0 = time.perf_counter()
-        try:
-            return self._roundtrip(make_frame(deadline_s), decode)
-        except (ConnectionError, OSError):
-            if not self.reconnect:
-                raise
-            telemetry.get_registry().inc("client_reconnects")
+        with self._io:
             try:
-                self._sock.close()
-            except OSError:
-                pass
-            remaining = deadline_s
-            if deadline_s is not None:
-                remaining = deadline_s - (time.perf_counter() - t0)
-                if remaining <= 0:
-                    telemetry.get_registry().inc("client_sheds_expired")
-                    raise wire.ShedError(
-                        f"{SHED_EXPIRED}: deadline budget "
-                        f"{deadline_s * 1e3:.1f}ms spent during reconnect"
-                    ) from None
-            self._sock = self._connect()
-            return self._roundtrip(make_frame(remaining), decode)
+                return self._roundtrip(make_frame(deadline_s), decode)
+            except (ConnectionError, OSError):
+                if not self.reconnect:
+                    raise
+                telemetry.get_registry().inc("client_reconnects")
+                try:
+                    self._sock.close()
+                except OSError:
+                    pass
+                remaining = deadline_s
+                if deadline_s is not None:
+                    remaining = deadline_s - (time.perf_counter() - t0)
+                    if remaining <= 0:
+                        telemetry.get_registry().inc("client_sheds_expired")
+                        raise wire.ShedError(
+                            f"{SHED_EXPIRED}: deadline budget "
+                            f"{deadline_s * 1e3:.1f}ms spent during "
+                            f"reconnect") from None
+                self._sock = self._connect()
+                return self._roundtrip(make_frame(remaining), decode)
 
     def _rpc_with_retry(self, make_frame, deadline_s: Optional[float] = None,
                         decode=wire.decode_reply):

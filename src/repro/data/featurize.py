@@ -6,11 +6,17 @@ request. Both are pure functions of their string inputs, so this module
 memoizes them: query/answer token rows by text, overlap features by pair.
 Bounded LRU (``OrderedDict`` recency order) keeps steady-state serving memory
 flat under heavy repeated traffic.
+
+``FeaturizationCache.featurize_many`` (the replica pool and the engine) and
+``featurize_grouped`` (the batched ranker) are the served paths' entry
+points: one ``featurize`` span per call, with ``featurize.encode`` (token
+rows) and ``featurize.pairs`` (overlap features) under it.
 """
 from __future__ import annotations
 
+import contextlib
 from collections import OrderedDict
-from typing import Dict, Sequence, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 import threading
 
 import numpy as np
@@ -29,13 +35,20 @@ class LRUCache:
         self.hits = 0
         self.misses = 0
 
-    def get(self, key):
+    def get(self, key, tally: Optional[List[int]] = None):
+        """The cached value or None. ``tally`` ([hits, misses]) also counts
+        this lookup for the caller alone: the shared counters mix every
+        thread's lookups."""
         with self._lock:
             if key in self._d:
                 self._d.move_to_end(key)
                 self.hits += 1
+                if tally is not None:
+                    tally[0] += 1
                 return self._d[key]
             self.misses += 1
+            if tally is not None:
+                tally[1] += 1
             return None
 
     def put(self, key, value):
@@ -67,8 +80,9 @@ class FeaturizationCache:
         self._pair_cache = LRUCache(capacity)
         self._words_cache = LRUCache(capacity)
 
-    def _row(self, text: str) -> np.ndarray:
-        row = self._tok_cache.get(text)
+    def _row(self, text: str, tally: Optional[List[int]] = None
+             ) -> np.ndarray:
+        row = self._tok_cache.get(text, tally)
         if row is None:
             row = np.asarray(self.tok.encode(text, self.max_len), np.int32)
             self._tok_cache.put(text, row)
@@ -93,9 +107,10 @@ class FeaturizationCache:
             self._words_cache.put(text, state)
         return state
 
-    def pair_feats(self, query: str, answer: str) -> np.ndarray:
+    def pair_feats(self, query: str, answer: str,
+                   tally: Optional[List[int]] = None) -> np.ndarray:
         key = (query, answer)
-        feats = self._pair_cache.get(key)
+        feats = self._pair_cache.get(key, tally)
         if feats is None:
             q_state, a_state = self._word_state(query), self._word_state(answer)
             feats = np.zeros((4,), np.float32)
@@ -113,7 +128,69 @@ class FeaturizationCache:
         return (self._row(query), self._row(answer),
                 self.pair_feats(query, answer))
 
-    def pair_feats_many(self, pairs: Sequence[Tuple[str, str]]) -> np.ndarray:
+    @contextlib.contextmanager
+    def _span(self, rows: int) -> Iterator[Tuple[object, List[int],
+                                                  List[int]]]:
+        """One call's ``featurize`` span, with ``rows`` and ``cpu_ms``.
+        Yields the tracer and two [hits, misses] tallies, one for answer
+        token rows and one for pair features, which the caller passes to
+        its own lookups; on exit they become ``row_hits``/``row_misses``,
+        ``pair_hits``/``pair_misses`` and their sums ``hits``/``misses``.
+        A query's token row is not tallied: looked up once per pair, it
+        would hit by the loop's shape alone."""
+        from repro.serving import telemetry
+        tracer = telemetry.get_tracer()
+        row_tally, pair_tally = [0, 0], [0, 0]
+        with tracer.span("featurize", rows=rows, cpu=True) as span:
+            yield tracer, row_tally, pair_tally
+            span.set_attr("row_hits", row_tally[0])
+            span.set_attr("row_misses", row_tally[1])
+            span.set_attr("pair_hits", pair_tally[0])
+            span.set_attr("pair_misses", pair_tally[1])
+            span.set_attr("hits", row_tally[0] + pair_tally[0])
+            span.set_attr("misses", row_tally[1] + pair_tally[1])
+
+    def featurize_many(self, pairs: Sequence[Tuple[str, str]]
+                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Stacked (query rows, answer rows, overlap features) for a
+        non-empty pair list, as ``featurize`` gives them pair by pair.
+
+        One ``featurize`` span a call (see ``_span``) with two children,
+        each with ``cpu_ms``: ``featurize.encode`` (every token row) and
+        ``featurize.pairs`` (every pair's overlap features). No span is
+        opened per pair."""
+        with self._span(len(pairs)) as (tracer, row_tally, pair_tally):
+            with tracer.span("featurize.encode", cpu=True):
+                q_tok = np.stack([self._row(q) for q, _ in pairs])
+                a_tok = np.stack([self._row(a, row_tally) for _, a in pairs])
+            with tracer.span("featurize.pairs", cpu=True):
+                feats = np.stack([self.pair_feats(q, a, pair_tally)
+                                  for q, a in pairs])
+        return q_tok, a_tok, feats
+
+    def featurize_grouped(self, groups: Sequence[Tuple[str, Sequence[str]]]
+                          ) -> Tuple[List[np.ndarray], List[np.ndarray],
+                                     np.ndarray]:
+        """(query rows, answer rows, overlap features) for every (query,
+        answer) of ``groups`` (a query and its answers each), in order:
+        each query's row is encoded once, the features of the whole list
+        come from ``pair_feats_many``. The same spans as
+        ``featurize_many``."""
+        pairs = [(q, a) for q, answers in groups for a in answers]
+        with self._span(len(pairs)) as (tracer, row_tally, pair_tally):
+            q_rows: List[np.ndarray] = []
+            a_rows: List[np.ndarray] = []
+            with tracer.span("featurize.encode", cpu=True):
+                for q, answers in groups:
+                    q_rows += [self.query_row(q)] * len(answers)
+                    a_rows += [self.answer_row(a, row_tally)
+                               for a in answers]
+            with tracer.span("featurize.pairs", cpu=True):
+                feats = self.pair_feats_many(pairs, pair_tally)
+        return q_rows, a_rows, feats
+
+    def pair_feats_many(self, pairs: Sequence[Tuple[str, str]],
+                        tally: Optional[List[int]] = None) -> np.ndarray:
         """Overlap features for a cross-query pair list: cached pairs come
         from the LRU, the misses go through one vectorized word-incidence
         matmul per stopword filter instead of a Python loop per pair."""
@@ -122,7 +199,7 @@ class FeaturizationCache:
         out = np.empty((len(pairs), 4), np.float32)
         miss = []
         for i, (q, a) in enumerate(pairs):
-            feats = self._pair_cache.get((q, a))
+            feats = self._pair_cache.get((q, a), tally)
             if feats is None:
                 miss.append(i)
             else:

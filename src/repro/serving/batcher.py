@@ -212,23 +212,28 @@ class MicroBatcher:
                     if i.trace is not None:
                         tracer.record("batcher.queue_wait", i.t_enq, t_deq,
                                       parent=i.trace, rows=i.n)
+                traced = [i for i in items if i.trace is not None]
                 t0 = time.perf_counter()
-                # Adopt the first traced item's context for the scorer call
-                # so kernel-side spans (Scorer buckets) attach to a real
-                # request tree — the batch is shared, so one tree hosts it.
-                batch_trace = next((i.trace for i in items
-                                    if i.trace is not None), None)
-                with tracer.activate(batch_trace):
+                if traced:
+                    # The compute span is opened live, under the first
+                    # traced item, on this thread: the scorer's spans nest
+                    # in it and a profile shows it. The batch is shared,
+                    # so the other items record the same interval.
+                    with tracer.span("batcher.compute",
+                                     parent=traced[0].trace,
+                                     rows=traced[0].n,
+                                     batch=int(q.shape[0])):
+                        scores = np.asarray(self.scorer(q, a, f))
+                else:
                     scores = np.asarray(self.scorer(q, a, f))
                 t1 = time.perf_counter()
                 registry.observe("batcher_compute_ms", (t1 - t0) * 1e3)
                 registry.observe("batcher_batch_rows", float(q.shape[0]),
                                  buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256))
-                for i in items:
-                    if i.trace is not None:
-                        tracer.record("batcher.compute", t0, t1,
-                                      parent=i.trace, rows=i.n,
-                                      batch=int(q.shape[0]))
+                for i in traced[1:]:
+                    tracer.record("batcher.compute", t0, t1,
+                                  parent=i.trace, rows=i.n,
+                                  batch=int(q.shape[0]))
                 per_row = (t1 - t0) / q.shape[0]
                 with self._lock:
                     self._row_scorer_s = (

@@ -155,12 +155,6 @@ class ReplicaPool:
             chosen.requests += 1
         return chosen
 
-    def _featurize_batch(self, pairs: Sequence[Tuple[str, str]]):
-        rows = [self.features.featurize(q, a) for q, a in pairs]
-        return (np.stack([r[0] for r in rows]),
-                np.stack([r[1] for r in rows]),
-                np.stack([r[2] for r in rows]))
-
     def submit(self, pairs: Sequence[Tuple[str, str]],
                deadline_abs: Optional[float] = None):
         """Route one request's pairs to a replica; returns the future.
@@ -171,7 +165,7 @@ class ReplicaPool:
         item never entered its queue — see ``MicroBatcher._enqueue``), so
         re-routing is lossless; a fresh pick sees the replacement batcher.
         """
-        q_tok, a_tok, feats = self._featurize_batch(pairs)
+        q_tok, a_tok, feats = self.features.featurize_many(pairs)
         for _ in range(3):
             fut = self._pick().batcher.submit_many(q_tok, a_tok, feats,
                                                    deadline_abs=deadline_abs)

@@ -79,18 +79,7 @@ class ServingEngine:
         t0 = time.perf_counter()
         tracer = telemetry.get_tracer()
         with tracer.span("engine.get_scores", rows=len(pairs)):
-            with tracer.span("featurize") as feat_span:
-                before = self.features.stats()
-                rows = [self._featurize(q, a) for q, a in pairs]
-                after = self.features.stats()
-                feat_span.set_attr("hits", int(after["feat_cache_hits"]
-                                               - before["feat_cache_hits"]))
-                feat_span.set_attr(
-                    "misses", int(after["feat_cache_misses"]
-                                  - before["feat_cache_misses"]))
-            q_tok = np.stack([r[0] for r in rows])
-            a_tok = np.stack([r[1] for r in rows])
-            feats = np.stack([r[2] for r in rows])
+            q_tok, a_tok, feats = self.features.featurize_many(pairs)
             out = self.batcher.submit_many(
                 q_tok, a_tok, feats, deadline_abs=deadline_abs).result()
         self.tracker.observe(time.perf_counter() - t0)

@@ -666,8 +666,14 @@ class Fabric:
         finished spans (router/client side) plus every reachable worker's
         spans fetched over MSG_STATS, optionally filtered to one trace.
         Returns ``telemetry.SpanRecord`` objects — feed them to
-        ``telemetry.span_tree`` / ``export_chrome_trace``."""
+        ``telemetry.span_tree`` / ``export_chrome_trace``.
+
+        A hedge's losing attempt may still be running when the caller has
+        its answer: its finished children would then be fetched without
+        the spans still open above them. The router's attempts settle
+        first, so every trace comes back whole."""
         assert self.router is not None
+        self.router.settle()
         spans = list(telemetry.get_tracer().finished())
         for ep in list(self.router._endpoints):
             try:

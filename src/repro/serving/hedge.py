@@ -58,6 +58,7 @@ class HedgedTransport:
         self._hedged = 0
         self._hedge_wins = 0
         self._observed = 0
+        self._in_flight = 0     # attempts started and not yet finished
 
     # ------------------------------------------------------------ delay --
 
@@ -79,32 +80,52 @@ class HedgedTransport:
                  role: str = "primary") -> None:
         lock = self._locks[idx]
         tracer = telemetry.get_tracer()
-        with lock:
-            t0 = time.perf_counter()
-            # Attempts run in fresh daemon threads, so the caller's span
-            # context is handed over explicitly: the attempt span — and the
-            # client span it wraps — joins the request's trace tree.
-            with tracer.activate(parent):
-                with tracer.span(f"hedge.{role}", endpoint=idx,
-                                 method=method) as sp:
-                    try:
-                        # The RPC stays under the endpoint lock by design:
-                        # a losing attempt keeps the framed stream to
-                        # itself until its reply is fully read (see module
-                        # docstring) — the lock IS the drain barrier.
-                        val = getattr(self._transports[idx], method)(*args)
-                    except Exception as e:  # noqa: BLE001 — raced, judged
-                        sp.set_attr("error", type(e).__name__)
-                        results.put((idx, e, None))
-                        return
-            dt = time.perf_counter() - t0
-        # Bookkeeping runs after the endpoint lock is released: the tracker
-        # and meta locks are only ever taken bare, never nested inside an
-        # endpoint lock, so a draining loser cannot stall stats readers.
-        self.tracker.observe(dt)
-        with self._meta:
-            self._observed += 1
-        results.put((idx, None, val))
+        try:
+            with lock:
+                t0 = time.perf_counter()
+                # Attempts run in fresh daemon threads, so the caller's span
+                # context is handed over explicitly: the attempt span — and
+                # the client span it wraps — joins the request's trace tree.
+                with tracer.activate(parent):
+                    with tracer.span(f"hedge.{role}", endpoint=idx,
+                                     method=method) as sp:
+                        try:
+                            # The RPC stays under the endpoint lock by
+                            # design: a losing attempt keeps the framed
+                            # stream to itself until its reply is fully
+                            # read (see module docstring) — the lock IS
+                            # the drain barrier.
+                            val = getattr(self._transports[idx],
+                                          method)(*args)
+                        except Exception as e:  # noqa: BLE001 — judged
+                            sp.set_attr("error", type(e).__name__)
+                            results.put((idx, e, None))
+                            return
+                dt = time.perf_counter() - t0
+            # Bookkeeping runs after the endpoint lock is released: the
+            # tracker and meta locks are only ever taken bare, never nested
+            # inside an endpoint lock, so a draining loser cannot stall
+            # stats readers.
+            self.tracker.observe(dt)
+            with self._meta:
+                self._observed += 1
+            results.put((idx, None, val))
+        finally:
+            with self._meta:
+                self._in_flight -= 1
+
+    def settle(self, timeout_s: float = 10.0) -> bool:
+        """Wait until no attempt is in flight: a losing attempt drains its
+        reply after the caller already has the winner's, and its spans on
+        either side close only then. True if every attempt finished."""
+        deadline = time.perf_counter() + timeout_s
+        while True:
+            with self._meta:
+                if not self._in_flight:
+                    return True
+            if time.perf_counter() >= deadline:
+                return False
+            time.sleep(0.001)
 
     def _pick_endpoints(self) -> "tuple":
         """Choose ``(primary, backup)`` endpoint indices for one request;
@@ -136,6 +157,8 @@ class HedgedTransport:
         # span context does not cross thread starts).
         parent = telemetry.get_tracer().current_context()
         results: "queue.Queue" = queue.Queue()
+        with self._meta:
+            self._in_flight += 1
         threading.Thread(target=self._attempt,
                          args=(primary, method, args, results, parent),
                          daemon=True).start()
@@ -158,6 +181,7 @@ class HedgedTransport:
         registry.inc("hedge_hedged")
         with self._meta:
             self._hedged += 1
+            self._in_flight += 1
         threading.Thread(target=self._attempt,
                          args=(backup, method, args, results, parent,
                                "hedge"),

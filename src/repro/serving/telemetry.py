@@ -34,17 +34,26 @@ measurement substrate that answers it for our stack:
     ``span_tree``/``format_span_tree`` render the per-request breakdown the
     paper's Tables 1-2 tabulate.
 
-Overhead: a span is two ``perf_counter`` calls and one locked deque append;
-a metric a locked dict update. Enabled telemetry costs <5% on the
-jit-batched pipeline row (``benchmarks.run --table trace`` measures it).
-``set_enabled(False)`` turns ``span()`` into a shared no-op for zero-cost
-opt-out.
+    While a JAX profile is being taken (``jax.profiler.start_trace``),
+    every span opened with ``span()`` is also a ``TraceAnnotation`` on the
+    profile's host plane, on the thread that runs it, under the span's
+    name and with its attributes: the program's spans sit on the device
+    trace's clock. ``record()``ed intervals are spent by no thread (a
+    queue wait), so they stay in the ring only.
+
+Overhead: a span is two ``perf_counter`` calls, one locked deque append
+and one ``TraceAnnotation.is_enabled()`` check (about 0.2 us with no
+profile running; a dict lookup in a process that never imported jax);
+``cpu=True`` adds two ``time.thread_time`` reads. A metric is a locked
+dict update. ``set_enabled(False)`` turns ``span()`` into a shared no-op
+for zero-cost opt-out.
 """
 from __future__ import annotations
 
 import json
 import os
 import struct
+import sys
 import threading
 import time
 from collections import deque
@@ -55,7 +64,6 @@ __all__ = [
     "get_registry", "get_tracer", "reset_all",
     "merge_snapshots", "split_by_label", "export_chrome_trace",
     "chrome_trace_events", "span_tree", "format_span_tree",
-    "stage_breakdown",
 ]
 
 #: Default histogram bucket upper bounds, in milliseconds (latency-shaped).
@@ -66,6 +74,19 @@ DEFAULT_BUCKETS_MS: Tuple[float, ...] = (
 #: this process shares one consistent wall clock (cross-process span trees
 #: align to within clock skew, which localhost fabrics don't have).
 _EPOCH_ANCHOR = time.time() - time.perf_counter()
+
+
+#: This process's id, read once (``os.getpid`` is a system call, and every
+#: finished span records it) and read again in a forked child.
+_PID = os.getpid()
+
+
+def _refresh_pid() -> None:
+    global _PID
+    _PID = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)
 
 
 def perf_to_epoch_us(t_perf: float) -> float:
@@ -324,30 +345,52 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` once jax is loaded, else None.
+    Looked up, never imported: a process that never imported jax has no
+    profile to annotate."""
+    prof = sys.modules.get("jax.profiler")
+    return getattr(prof, "TraceAnnotation", None)
+
+
 class Span:
     """A live span; use as a context manager (``tracer.span(...)``)."""
 
-    __slots__ = ("_tracer", "name", "context", "parent_id", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "context", "parent_id", "attrs", "_t0",
+                 "_cpu0", "_profiled")
 
     def __init__(self, tracer: "Tracer", name: str, context: SpanContext,
-                 parent_id: int, attrs: Dict[str, Any]):
+                 parent_id: int, attrs: Dict[str, Any], cpu: bool = False):
         self._tracer = tracer
         self.name = name
         self.context = context
         self.parent_id = parent_id
         self.attrs = attrs
+        self._cpu0 = 0.0 if cpu else None
+        self._profiled = None
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
 
     def __enter__(self) -> "Span":
+        annotation = _profiler_annotation()
+        if annotation is not None and annotation.is_enabled():
+            self._profiled = annotation(self.name)
+            self._profiled.__enter__()
+        if self._cpu0 is not None:
+            self._cpu0 = time.thread_time()
         self._t0 = time.perf_counter()
         self._tracer._push(self.context)
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        if self._cpu0 is not None:
+            self.attrs["cpu_ms"] = (time.thread_time() - self._cpu0) * 1e3
         self._tracer._pop()
+        if self._profiled is not None:
+            self._profiled.set_metadata(**self.attrs)
+            self._profiled.__exit__(*exc)
         self._tracer._record_finished(
             self.context.trace_id, self.context.span_id, self.parent_id,
             self.name, self._t0, t1, self.attrs)
@@ -409,10 +452,13 @@ class Tracer:
 
     # ----------------------------------------------------------- spans --
 
-    def span(self, name: str, parent: Optional[SpanContext] = None,
-             **attrs):
+    def span(self, name: str, parent: Optional[SpanContext] = None, *,
+             cpu: bool = False, **attrs):
         """Open a child span of ``parent`` (default: the thread's current
-        span; a fresh trace root when there is none)."""
+        span; a fresh trace root when there is none). ``cpu=True`` also
+        stores the thread CPU time spent inside it as ``cpu_ms``: below
+        the span's wall time, the thread waited (for the GIL, the device,
+        a lock) instead of working."""
         if not self._enabled:
             return NOOP_SPAN
         if parent is None:
@@ -422,16 +468,19 @@ class Tracer:
         else:
             trace_id, parent_id = self._ids.next(), 0
         ctx = SpanContext(trace_id, self._ids.next())
-        return Span(self, name, ctx, parent_id, attrs)
+        return Span(self, name, ctx, parent_id, attrs, cpu)
 
     def record(self, name: str, t0_perf: float, t1_perf: float,
                parent: Optional[SpanContext] = None, **attrs
                ) -> Optional[SpanContext]:
         """Record an already-measured interval as a finished span with an
         explicit parent — the worker-thread pattern (a MicroBatcher item's
-        queue wait / compute split is timed by the batch loop, not by a
-        ``with`` block in the submitting thread). Returns the new span's
-        context (None when disabled)."""
+        queue wait is timed by the batch loop, not by a ``with`` block in
+        the submitting thread). The span goes to the ring only: no thread
+        spends a queue wait, so there is nothing to show on a profile's
+        thread lines. Work a thread does is a ``span()``, opened where the
+        thread does it. Returns the new span's context (None when
+        disabled)."""
         if not self._enabled:
             return None
         if parent is not None:
@@ -448,7 +497,7 @@ class Tracer:
                          attrs: Dict[str, Any]) -> None:
         rec = SpanRecord(trace_id, span_id, parent_id, name,
                          perf_to_epoch_us(t0), (t1 - t0) * 1e6,
-                         os.getpid(), threading.get_ident(), attrs)
+                         _PID, threading.get_ident(), attrs)
         with self._lock:
             self._ring.append(rec)
 
@@ -563,19 +612,6 @@ def format_span_tree(spans: Sequence[SpanRecord],
     for root in roots:
         walk(root, 0)
     return "\n".join(lines)
-
-
-def stage_breakdown(spans: Sequence[SpanRecord]) -> Dict[str, Dict[str, float]]:
-    """Aggregate spans by name: count, total/mean ms — the per-stage
-    latency table behind ``benchmarks.run --table trace``."""
-    agg: Dict[str, Dict[str, float]] = {}
-    for s in spans:
-        row = agg.setdefault(s.name, {"count": 0.0, "total_ms": 0.0})
-        row["count"] += 1
-        row["total_ms"] += s.dur_us / 1e3
-    for row in agg.values():
-        row["mean_ms"] = row["total_ms"] / max(row["count"], 1.0)
-    return agg
 
 
 # ================================================= process-wide default ==

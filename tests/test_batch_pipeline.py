@@ -17,6 +17,7 @@ from repro.data import qa as QA
 from repro.data.featurize import FeaturizationCache, LRUCache
 from repro.data.tokenizer import HashingTokenizer, overlap_features
 from repro.models import sm_cnn
+from repro.serving import telemetry
 from repro.serving.batcher import MicroBatcher
 
 
@@ -242,3 +243,89 @@ def test_engine_uses_cache_and_submit_many(world):
     s = eng.stats()
     assert s["feat_cache_hit_rate"] > 0.5  # repeats hit the LRU
     assert s["mean_batch"] > 1  # rows went through as sub-batches
+
+
+def test_coalesced_rerank_opens_the_pool_featurize_spans(world):
+    """The batched ranker's rerank stage opens the same ``featurize`` span
+    as the replica pool: ``featurize.encode`` then ``featurize.pairs``
+    under it, each with ``cpu_ms``, and this call's own lookups of answer
+    rows and pair features (two a candidate), counted apart per cache."""
+    cfg, params, corpus, tok, index = world
+    scorer = BK.make_scorer("numpy", params, cfg, buckets=(8, 64))
+    stages = _stages(scorer, world, cutoff=False)
+    tracer = telemetry.get_tracer()
+    tracer.clear()
+    results = BatchedMultiStageRanker(stages).run_batch(corpus.questions[:4])
+    spans = tracer.finished()
+    (rerank,) = [s for s in spans if s.name.startswith("stage.rerank")]
+    (feat,) = [s for s in spans if s.name == "featurize"]
+    kids = sorted((s for s in spans if s.parent_id == feat.span_id),
+                  key=lambda s: s.ts_us)
+    assert feat.parent_id == rerank.span_id
+    assert [k.name for k in kids] == ["featurize.encode", "featurize.pairs"]
+    assert min(s.attrs["cpu_ms"] for s in [feat] + kids) > 0
+    rows = sum(len(t[1][0].candidates) for t in results)
+    assert feat.attrs["rows"] == rows
+    assert feat.attrs["hits"] + feat.attrs["misses"] == 2 * rows
+    assert (feat.attrs["row_hits"] + feat.attrs["row_misses"]
+            == feat.attrs["pair_hits"] + feat.attrs["pair_misses"] == rows)
+
+
+# ------------------------------------------------------- retrieval spans --
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_retrieval_stage_splits_into_gather_score_segment(world, batched):
+    """Under each ``stage.bm25-h*`` span, ``bm25.gather``, ``bm25.score``
+    and ``bm25.segment`` follow one another without overlap and cover at
+    least 90% of it; the stage span carries its thread CPU time."""
+    cfg, params, corpus, tok, index = world
+    stage = PL.RetrievalStage(index, corpus.documents, tok, h=8)
+    queries = list(corpus.questions[:6])
+    if batched:
+        ranker = BatchedMultiStageRanker([stage])
+        run = lambda: ranker.run_batch(queries)  # noqa: E731
+    else:
+        ranker = PL.MultiStageRanker([stage])
+        run = lambda: [ranker.run(q) for q in queries]  # noqa: E731
+    run()                                   # compile outside the spans
+    tracer = telemetry.get_tracer()
+    tracer.clear()
+    run()
+    spans = tracer.finished()
+    stages = [s for s in spans if s.name == "stage.bm25-h8"]
+    assert len(stages) == (1 if batched else len(queries))
+    covered = total = 0.0
+    for st in stages:
+        kids = sorted((s for s in spans if s.parent_id == st.span_id),
+                      key=lambda s: s.ts_us)
+        assert [k.name for k in kids] == ["bm25.gather", "bm25.score",
+                                          "bm25.segment"]
+        for a, b in zip(kids, kids[1:]):
+            assert a.ts_us + a.dur_us <= b.ts_us
+        assert st.ts_us <= kids[0].ts_us
+        assert kids[-1].ts_us + kids[-1].dur_us <= st.ts_us + st.dur_us
+        covered += sum(k.dur_us for k in kids)
+        total += st.dur_us
+        assert 0 < st.attrs["cpu_ms"]
+    assert covered >= 0.9 * total
+
+
+def test_retrieve_reads_lazy_terms_inside_gather(world):
+    """Terms handed over lazily are encoded inside ``bm25.gather``; the
+    results equal those of eager terms."""
+    cfg, params, corpus, tok, index = world
+    q = corpus.questions[0]
+    seen = []
+
+    def lazy():
+        seen.append(telemetry.get_tracer().current_context())
+        yield from tok.encode(q)
+
+    tracer = telemetry.get_tracer()
+    tracer.clear()
+    eager = BM.retrieve_many(index, [tok.encode(q)], h=5)
+    got = BM.retrieve_many(index, [lazy()], h=5)
+    np.testing.assert_array_equal(got[0][1], eager[0][1])
+    np.testing.assert_array_equal(got[0][0], eager[0][0])
+    gathers = [s for s in tracer.finished() if s.name == "bm25.gather"]
+    assert seen and seen[0].span_id == gathers[-1].span_id

@@ -11,6 +11,7 @@ from repro.core import backends as BK
 from repro.data import qa as QA
 from repro.data.tokenizer import HashingTokenizer
 from repro.models import sm_cnn
+from repro.serving import telemetry
 from repro.serving.admission import (SHED_EXPIRED, SHED_LATE,
                                      SHED_QUEUE_FULL, SHED_TOO_LARGE,
                                      AdmissionController)
@@ -33,6 +34,103 @@ def _pairs(corpus, n):
         out.append((corpus.questions[i % len(corpus.questions)],
                     corpus.documents[i % len(corpus.documents)][0]))
     return out
+
+
+# ------------------------------------------------------ featurize spans --
+
+def _pool_spans(pool, pairs):
+    """The spans of one ``pool.get_scores`` call, by name."""
+    tracer = telemetry.get_tracer()
+    tracer.clear()
+    with tracer.span("test.request") as root:
+        pool.get_scores(pairs)
+    by_name = {}
+    for s in tracer.finished(trace_id=root.context.trace_id):
+        by_name.setdefault(s.name, []).append(s)
+    return by_name
+
+
+@pytest.fixture()
+def numpy_pool(world):
+    cfg, params, corpus, tok = world
+    pool = ReplicaPool.build("numpy", params, cfg, tok, corpus.idf,
+                             n_replicas=1, buckets=(1, 8, 64, 1024),
+                             max_batch=1024)
+    yield pool
+    pool.stop()
+
+
+def test_pool_featurize_span_and_children(world, numpy_pool):
+    """``featurize`` sits under ``pool.get_scores`` with
+    ``featurize.encode`` then ``featurize.pairs`` under it, and counts this
+    call's own lookups of answer rows and pair features."""
+    cfg, params, corpus, tok = world
+    pairs = [(corpus.questions[0], corpus.documents[i][0]) for i in range(10)]
+    spans = _pool_spans(numpy_pool, pairs)
+    (call,) = spans["pool.get_scores"]
+    (feat,) = spans["featurize"]
+    (enc,) = spans["featurize.encode"]
+    (prs,) = spans["featurize.pairs"]
+    assert feat.parent_id == call.span_id
+    assert enc.parent_id == prs.parent_id == feat.span_id
+    assert enc.ts_us + enc.dur_us <= prs.ts_us
+    assert feat.attrs["rows"] == 10
+    assert min(s.attrs["cpu_ms"] for s in (feat, enc, prs)) > 0
+    # a fresh cache: every answer row and pair feature misses once; the
+    # query's row, looked up for every pair, is not counted
+    assert (feat.attrs["hits"], feat.attrs["misses"]) == (0, 20)
+    assert (feat.attrs["row_misses"], feat.attrs["pair_misses"]) == (10, 10)
+    again = _pool_spans(numpy_pool, pairs)["featurize"][0]
+    assert (again.attrs["hits"], again.attrs["misses"]) == (20, 0)
+    assert (again.attrs["row_hits"], again.attrs["pair_hits"]) == (10, 10)
+
+
+def test_pool_opens_as_many_spans_for_1000_candidates_as_for_10(
+        world, numpy_pool):
+    cfg, params, corpus, tok = world
+    few = _pool_spans(numpy_pool, _pairs(corpus, 10))
+    many = _pool_spans(numpy_pool, _pairs(corpus, 1000))
+    assert {k: len(v) for k, v in many.items()} == {
+        k: len(v) for k, v in few.items()}
+
+
+def test_pool_featurization_equals_the_pair_by_pair_loop(world, numpy_pool):
+    """Two passes over the pairs give the arrays the one-pass loop gave,
+    bit for bit."""
+    cfg, params, corpus, tok = world
+    from repro.data.featurize import FeaturizationCache
+    pairs = _pairs(corpus, 37) + [("", corpus.documents[0][1])]
+    fresh = FeaturizationCache(tok, corpus.idf, cfg.max_len)
+    rows = [fresh.featurize(q, a) for q, a in pairs]
+    want = [np.stack([r[k] for r in rows]) for k in range(3)]
+    for _ in range(2):                      # cold, then from the cache
+        got = numpy_pool.features.featurize_many(pairs)
+        for g, w in zip(got, want):
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(g, w)
+
+
+def test_pool_featurize_counts_only_its_own_lookups(world, numpy_pool):
+    """Under concurrent callers on the shared cache, every ``featurize``
+    span still counts exactly its own two lookups a pair (answer row,
+    pair features)."""
+    cfg, params, corpus, tok = world
+    tracer = telemetry.get_tracer()
+    tracer.clear()
+
+    def call(i):
+        with tracer.span("test.request"):
+            numpy_pool.get_scores(_pairs(corpus, 20 + i))
+
+    threads = [threading.Thread(target=call, args=(i,)) for i in range(6)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    feats = [s for s in tracer.finished() if s.name == "featurize"]
+    assert len(feats) == 6
+    for s in feats:
+        assert s.attrs["hits"] + s.attrs["misses"] == 2 * s.attrs["rows"]
 
 
 # ---------------------------------------------------------------- replica pool

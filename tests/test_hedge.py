@@ -283,3 +283,16 @@ def test_fresh_requests_route_around_busy_endpoint():
     assert fast.calls == 4 and slow.calls == 0
     drain.join(timeout=2.0)
     assert not drain.is_alive()
+
+
+def test_settle_waits_for_the_losing_attempt():
+    """After the winner's answer, ``settle`` returns once the loser has
+    drained its reply too, so nothing of the call is still running."""
+    slow = _StubTransport("slow", 1, delay_s=0.3)
+    fast = _StubTransport("fast", 2)
+    ht = HedgedTransport([slow, fast], hedge_s=0.02)
+    assert ht.rank_batch(["q"]) == [[(2, 0, 2.0)]]
+    assert slow.completed == 0              # the loser is still running
+    assert ht.settle(timeout_s=5.0)
+    assert slow.completed == 1
+    assert ht.settle(timeout_s=0.0)         # nothing in flight: at once

@@ -104,3 +104,42 @@ def test_service_sequential_clients(service):
         t.join(timeout=10)
     assert len(results) == 3
     assert len(set(round(r, 9) for r in results)) == 1
+
+
+def test_client_shared_by_threads_keeps_replies_apart(service):
+    """Threads sharing one Client (as the fabric's probe thread and its
+    supervisor share a control connection) each get their own reply:
+    batch sizes and scores never cross, under a short switch interval."""
+    import os
+    import sys
+    srv, handler, corpus = service
+    q = corpus.questions[0]
+    answers = [corpus.documents[i % len(corpus.documents)][0]
+               for i in range(8)]
+    want = {n: handler.get_scores([(q, a) for a in answers[:n]])
+            for n in range(1, 9)}
+    cl = SV.Client(srv.address)
+    errors = []
+
+    def worker(n):
+        try:
+            for _ in range(15):
+                got = cl.get_score_batch([(q, a) for a in answers[:n]])
+                np.testing.assert_allclose(got, want[n], rtol=1e-9)
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(1 + i % 8,))
+                   for i in range((os.cpu_count() or 1) + 4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+        cl.close()
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []

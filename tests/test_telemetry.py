@@ -1,8 +1,14 @@
 """Telemetry fabric unit tests: registry flattening/merging, tracer context
 propagation (stack, explicit parent, cross-thread activation), Chrome trace
-export, and the span-tree/breakdown renderers."""
+export, the span-tree renderers, and spans on a JAX profile's host
+plane."""
+import glob
 import json
+import os
+import subprocess
+import sys
 import threading
+import time
 
 import pytest
 
@@ -264,17 +270,6 @@ def test_format_span_tree_indents_by_depth():
     assert "rows=80" in lines[1]
 
 
-def test_stage_breakdown_aggregates_by_name():
-    tr = Tracer()
-    for _ in range(3):
-        with tr.span("stage.bm25"):
-            pass
-    agg = telemetry.stage_breakdown(tr.finished())
-    assert agg["stage.bm25"]["count"] == 3
-    assert agg["stage.bm25"]["mean_ms"] == pytest.approx(
-        agg["stage.bm25"]["total_ms"] / 3)
-
-
 def test_export_chrome_trace_validates(tmp_path):
     """The exported file must be loadable Chrome trace-event JSON: a
     traceEvents list of complete ("X") events with µs ts/dur and
@@ -320,3 +315,98 @@ def test_reset_all_clears_default_registry_and_tracer():
     telemetry.reset_all()
     assert "junk" not in telemetry.get_registry().snapshot()
     assert telemetry.get_tracer().finished() == []
+
+
+# --------------------------------------------------- the profiler's clock --
+
+def _host_events(log_dir):
+    """{name: [(line index, start_ns, end_ns, stats)]} over every /host:
+    plane of the newest profile under ``log_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for li, line in enumerate(plane.lines):
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (li, e.start_ns, e.end_ns, dict(e.stats)))
+    return out
+
+
+def test_spans_show_on_the_profile_host_plane(tmp_path):
+    """While a JAX profile is taken, a span is a host-plane event under its
+    name, on its own thread's line, nested in its parent's interval, and
+    still lands in the ring."""
+    import jax
+    tr = Tracer()
+
+    def worker():
+        with tr.span("test.worker_span"):
+            time.sleep(0.002)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("test.outer_span", rows=3):
+            with tr.span("test.inner_span"):
+                time.sleep(0.002)
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join(timeout=10)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    (outer,) = events["test.outer_span"]
+    (inner,) = events["test.inner_span"]
+    (other,) = events["test.worker_span"]
+    assert outer[0] == inner[0] != other[0]      # one line per thread
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert outer[3]["rows"] == 3                  # the span's attributes
+    assert {s.name for s in tr.finished()} == {
+        "test.outer_span", "test.inner_span", "test.worker_span"}
+
+
+def test_spans_land_in_the_ring_with_no_profile_running():
+    from jax.profiler import TraceAnnotation
+    assert not TraceAnnotation.is_enabled()
+    tr = Tracer()
+    with tr.span("a", rows=1):
+        with tr.span("b"):
+            pass
+    spans = {s.name: s for s in tr.finished()}
+    assert set(spans) == {"a", "b"}
+    assert spans["b"].parent_id == spans["a"].span_id
+    assert spans["a"].attrs == {"rows": 1}
+
+
+def test_telemetry_runs_without_jax():
+    """Wire-only processes never import jax; spans still work there."""
+    code = ("import sys\n"
+            "from repro.serving import telemetry\n"
+            "tr = telemetry.Tracer()\n"
+            "with tr.span('x', cpu=True):\n"
+            "    pass\n"
+            "assert [s.name for s in tr.finished()] == ['x']\n"
+            "assert 'jax' not in sys.modules\n")
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        p for p in sys.path if p))
+    subprocess.run([sys.executable, "-c", code], check=True, env=env,
+                   timeout=60)
+
+
+def test_cpu_span_carries_thread_cpu_time():
+    tr = Tracer()
+    with tr.span("sleeps", cpu=True):
+        time.sleep(0.05)
+    with tr.span("spins", cpu=True):
+        t_end = time.perf_counter() + 0.02
+        while time.perf_counter() < t_end:
+            pass
+    with tr.span("plain"):
+        pass
+    spans = {s.name: s for s in tr.finished()}
+    assert spans["sleeps"].attrs["cpu_ms"] < 0.5 * spans["sleeps"].dur_us / 1e3
+    assert 0 < spans["spins"].attrs["cpu_ms"] <= spans["spins"].dur_us / 1e3 + 1
+    assert "cpu_ms" not in spans["plain"].attrs
