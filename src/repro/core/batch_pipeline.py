@@ -119,11 +119,11 @@ class BatchedMultiStageRanker:
         active = [i for i, c in enumerate(states) if c]
         segments: List[Tuple[int, int]] = [(i, len(states[i]))
                                            for i in active]
-        q_rows, a_rows, feats = cache.featurize_grouped(
+        q_tok, a_tok, feats = cache.featurize_grouped(
             [(queries[i], [c.text for c in states[i]]) for i in active])
 
-        if q_rows:
-            scores = stage.scorer(np.stack(q_rows), np.stack(a_rows), feats)
+        if len(feats):
+            scores = stage.scorer(q_tok, a_tok, feats)
         else:
             scores = np.zeros((0,), np.float32)
 

@@ -1,6 +1,8 @@
 """Batched cross-query pipeline engine: equivalence with the sequential
 ranker on every backend, Scorer chunking past the top bucket, sub-batch
 micro-batching (submit_many), and featurization-cache behaviour."""
+import itertools
+import sys
 import threading
 import time
 
@@ -13,6 +15,7 @@ from repro.core import backends as BK
 from repro.core import bm25 as BM
 from repro.core import pipeline as PL
 from repro.core.batch_pipeline import BatchedMultiStageRanker, verify_equivalence
+from repro.data import featurize as FZ
 from repro.data import qa as QA
 from repro.data.featurize import FeaturizationCache, LRUCache
 from repro.data.tokenizer import HashingTokenizer, overlap_features
@@ -226,6 +229,188 @@ def test_pair_feats_many_matches_scalar_formula(world):
                                rtol=0, atol=1e-6)   # cold: matmul path
     np.testing.assert_allclose(cache.pair_feats_many(pairs), ref,
                                rtol=0, atol=1e-6)   # warm: LRU path
+
+
+#: Words with an idf of their own, beside the corpus's; "zebra", "quokka"
+#: and "numbat" have none (idf 0), "what", "is", "the", "of" are stopwords.
+_IDF = {"cat": 2.5, "dog": 1.25, "bird": 0.7, "mat": 3.0, "the": 0.1,
+        "what": 0.3, "is": 0.2, "don't": 1.7, "stop": 0.4, "cat's": 2.2,
+        "w3": 1.1, "w17": 0.9, "w39": 0.6}
+_LONG = " ".join(f"w{i}" for i in range(40))
+
+#: Each case is a list of calls on one cache, each call a list of pairs.
+_CASES = {
+    "empty_text": [[("", "cat dog"), ("what is the cat", ""), ("", "")]],
+    "stopword_only_query": [[("what is the", "the cat is on the mat"),
+                             ("of the", "dog bird")]],
+    "words_absent_from_idf": [[("zebra quokka cat", "quokka zebra numbat"),
+                               ("numbat", "numbat numbat")]],
+    "repeated_words": [[("cat cat dog cat the the", "dog dog cat bird dog"),
+                        ("dog dog", "dog")]],
+    "answer_past_max_len": [[("w3 w17 w39", _LONG),
+                             ("w39 zebra", _LONG + " " + _LONG)]],
+    "case_and_apostrophes": [[("Don't STOP the Cat's", "don't stop THE "
+                               "cat's DON'T, Cat!"),
+                              ("CAT'S dog", "cats' cat's o'clock")]],
+    "two_queries": "corpus:2",
+    "three_queries": "corpus:3",
+    "hits_mixed_with_misses": "corpus:again",
+}
+_ENTRY_POINTS = ("featurize_many", "featurize_grouped", "pair_feats_many",
+                 "featurize")
+
+
+def _calls(case, corpus):
+    calls = _CASES[case]
+    if not isinstance(calls, str):
+        return calls
+    sents = [s for d in corpus.documents for s in d]
+    qs = corpus.questions
+    if calls == "corpus:again":   # a second call repeats half the first
+        first = [(qs[0], a) for a in sents[:8]] + [(qs[1], sents[0])]
+        return [first, first[4:] + [(qs[1], a) for a in sents[5:12]]
+                + [(qs[0], "cat dog zebra")]]
+    n = int(calls[-1])
+    call = [(qs[k], a) for k in range(n) for a in sents[5 * k:5 * k + 9]]
+    return [call + call[:3] + [(qs[n - 1], sents[0])]]  # repeats too
+
+
+def _by_entry_point(cache, entry, call):
+    if entry == "featurize_many":
+        return cache.featurize_many(call)
+    if entry == "featurize_grouped":
+        groups = [(q, [a for _, a in g])
+                  for q, g in itertools.groupby(call, key=lambda p: p[0])]
+        return cache.featurize_grouped(groups)
+    if entry == "pair_feats_many":
+        return None, None, cache.pair_feats_many(call)
+    rows = [cache.featurize(q, a) for q, a in call]
+    return tuple(np.stack([r[k] for r in rows]) for k in range(3))
+
+
+@pytest.mark.parametrize("entry", _ENTRY_POINTS)
+@pytest.mark.parametrize("case", list(_CASES))
+def test_featurization_entry_points_match_the_reference(world, case, entry):
+    """Every entry point gives ``HashingTokenizer.encode``'s token rows bit
+    for bit and ``overlap_features`` to float32 rounding (the sums run in
+    another order), call after call on one cache."""
+    cfg, params, corpus, tok, index = world
+    idf = dict(corpus.idf, **_IDF)
+    max_len = 16
+    cache = FeaturizationCache(tok, idf, max_len, capacity=4096)
+    for call in _calls(case, corpus):
+        q_tok, a_tok, feats = _by_entry_point(cache, entry, call)
+        ref = np.stack([overlap_features(tok.words(q), tok.words(a), idf)
+                        for q, a in call])
+        assert feats.dtype == np.float32
+        np.testing.assert_allclose(feats, ref, rtol=1e-6, atol=1e-7)
+        if q_tok is None:
+            continue
+        for rows, k in ((q_tok, 0), (a_tok, 1)):
+            want = np.asarray([tok.encode(p[k], max_len) for p in call],
+                              np.int32)
+            assert rows.dtype == np.int32
+            np.testing.assert_array_equal(rows, want)
+
+
+def _unseen_words_calls(n_calls, seed):
+    """Calls of 12 pairs whose texts are mostly words no call met before,
+    each call sharing half its pairs with the next."""
+    rng = np.random.default_rng(seed)
+
+    def text(n):
+        return " ".join(f"u{int(rng.integers(0, 5000))}x" for _ in range(n))
+    pairs = [(text(4), text(int(rng.integers(1, 30))))
+             for _ in range(6 * (n_calls + 1))]
+    return [pairs[6 * c:6 * c + 12] for c in range(n_calls)]
+
+
+def test_word_table_under_four_threads(world):
+    """Four threads featurize overlapping calls full of unseen words on
+    one cache: each result equals the one-thread result, and every word
+    was interned once, with its own entries."""
+    cfg, params, corpus, tok, index = world
+    calls = _unseen_words_calls(48, seed=5)
+    idf = {f"u{i}x": 0.01 * i for i in range(0, 5000, 3)}
+    alone = FeaturizationCache(tok, idf, cfg.max_len)
+    want = [alone.featurize_many(c) for c in calls]
+    shared = FeaturizationCache(tok, idf, cfg.max_len, capacity=64)
+    got = [None] * len(calls)
+
+    def work(t):
+        for i in range(t, len(calls), 4):
+            got[i] = shared.featurize_many(calls[i])
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(t,))
+                   for t in range(4)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    for g, w in zip(got, want):
+        for a, b in zip(g, w):
+            np.testing.assert_array_equal(a, b)
+    table = shared.word_table
+    ids = dict(table._ids)
+    assert sorted(ids.values()) == list(range(len(ids)))
+    assert set(ids) == {w for c in calls for p in c for t in p
+                        for w in tok.words(t)}
+    token_ids, word_idf, stop = table._arrays
+    for w, i in ids.items():
+        assert token_ids[i] == tok.encode(w)[0]
+        assert word_idf[i] == idf.get(w, 0.0) and not stop[i]
+
+
+@pytest.mark.parametrize("bound", [0, 1, 7, 60])
+def test_word_table_past_its_bound(world, monkeypatch, bound):
+    """Past ``MAX_WORDS`` words are hashed for the call alone: the table
+    stops growing, results are unchanged, and each such word counts as a
+    miss in every call that meets it."""
+    cfg, params, corpus, tok, index = world
+    calls = _unseen_words_calls(6, seed=9) + [_CASES["repeated_words"][0]]
+    idf = dict(_IDF, **{f"u{i}x": 0.5 for i in range(0, 5000, 2)})
+    want = [FeaturizationCache(tok, idf, cfg.max_len).featurize_many(c)
+            for c in calls]
+    monkeypatch.setattr(FZ, "MAX_WORDS", bound)
+    cache = FeaturizationCache(tok, idf, cfg.max_len)
+    for call, w in zip(calls, want):
+        for a, b in zip(cache.featurize_many(call), w):
+            np.testing.assert_array_equal(a, b)
+    words = {w for c in calls for p in c for t in p for w in tok.words(t)}
+    assert len(cache.word_table) == min(bound, len(words))
+    tally = [0, 0]
+    cache.word_table.lookup(["u1x", "u1x", "cat"], tally)
+    assert sum(tally) == 3
+    assert len(cache.word_table) == min(bound, len(words))
+
+
+def test_featurize_span_counts_word_table_lookups(world):
+    """``word_hits``/``word_misses`` count the word table's lookups of the
+    words of the texts the token-row LRU missed (a miss once per word the
+    call interned; a cached text's words come with its row);
+    ``hits``/``misses`` still count one answer-row and one pair-feature
+    lookup a pair."""
+    cfg, params, corpus, tok, index = world
+    cache = FeaturizationCache(tok, _IDF, cfg.max_len)
+    tracer = telemetry.get_tracer()
+    tracer.clear()
+    first = [("a b c", "b c d d"), ("a b c", "e f")]
+    cache.featurize_many(first)          # 9 words, 6 of them new
+    cache.featurize_many(first)          # every row and pair cached
+    cache.featurize_many([("a b c", "a g"), ("a b c", "e f")])  # "a g" new
+    spans = [s.attrs for s in tracer.finished() if s.name == "featurize"]
+    assert [(a["word_hits"], a["word_misses"]) for a in spans] == [
+        (3, 6), (0, 0), (1, 1)]
+    assert [(a["hits"], a["misses"]) for a in spans] == [
+        (0, 4), (4, 0), (2, 2)]
+    assert [(a["row_hits"], a["pair_hits"]) for a in spans] == [
+        (0, 0), (2, 2), (1, 1)]
 
 
 def test_engine_uses_cache_and_submit_many(world):
