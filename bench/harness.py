@@ -1,9 +1,9 @@
 """One benchmark run of one cell: set up, measure a window, check.
 
 The cell comes from ``BENCHMARK.json`` by name; its configuration file,
-traffic file and per-layer metric readers are found by the names there,
-so a new cell, mix or metric is a new file and a new entry, never an
-edit here.
+traffic file, model family (``bench/families``) and per-layer metric
+readers are found by the names there, so a new cell, mix, metric or
+model family is a new file and a new entry, never an edit here.
 
 A run: make the corpus, index and queries from the seed; draw the weights
 on the device; stand up the served stack (``stack``); compile and warm
@@ -33,7 +33,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from bench import corpus as C
-from bench import devtrace, loadgen, reference
+from bench import devtrace, families, loadgen, reference
 
 
 #: Seconds the load generator waits, after the window closes, for replies
@@ -105,11 +105,6 @@ def use_compile_cache(root: Path) -> None:
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
-
-
-def model_config(config: Dict):
-    from repro.configs.base import TextPairConfig
-    return TextPairConfig(name=config["name"], **config["model"])
 
 
 @dataclasses.dataclass
@@ -228,14 +223,6 @@ def _warm_bm25(index, cfg_index, h: int, budget: int) -> int:
     return n
 
 
-def _warm_scorers(pool, cfg, buckets) -> None:
-    for rep in pool.replicas:
-        for b in buckets:
-            rep.batcher.scorer(np.zeros((b, cfg.max_len), np.int32),
-                               np.zeros((b, cfg.max_len), np.int32),
-                               np.zeros((b, cfg.n_extra_feats), np.float32))
-
-
 @dataclasses.dataclass
 class Setup:
     """A served stack ready for its window, and what set-up made."""
@@ -247,6 +234,7 @@ class Setup:
     n_warm: int
     key_bits: int
     model: Dict
+    family: types.ModuleType
     devices: List
     setup_s: float
 
@@ -292,16 +280,16 @@ def setup(cell: Cell, seed: int, n_timed: int, require_chip: bool = True,
 
     from repro.core.bm25 import BM25Index
     from repro.data.tokenizer import HashingTokenizer
-    from repro.models import sm_cnn
 
     config, traffic = cell.config, cell.traffic
     m = dict(config["model"])
-    cfg = model_config(config)
+    family = families.load(cell.root, config)
+    cfg = family.program_config(config)
     pipe = config["pipeline"]
-    corpus = C.generate(config["corpus"], cfg.vocab_size, seed)
+    corpus = C.generate(config["corpus"], m["vocab_size"], seed)
     idf = corpus.idf
     phase("corpus")
-    idx = C.build_index(corpus, cfg.vocab_size)
+    idx = C.build_index(corpus, m["vocab_size"])
     prog_index = BM25Index(idx.term_ptr, idx.post_docs, idx.post_tf, idx.idf,
                            idx.doc_len, idx.avg_dl, idx.n_docs)
     phase("index")
@@ -317,19 +305,19 @@ def setup(cell: Cell, seed: int, n_timed: int, require_chip: bool = True,
     phase("queries")
 
     key_bits = C.jax_key_bits(seed)
-    init = jax.jit(lambda k: sm_cnn.init_sm_cnn(k, cfg))
+    init = jax.jit(lambda k: family.init_params(k, cfg))
     params = jax.block_until_ready(init(jax.random.PRNGKey(key_bits)))
     phase("weights")
 
     from bench import stack
-    tok = HashingTokenizer(cfg.vocab_size)
+    tok = HashingTokenizer(m["vocab_size"])
     world = types.SimpleNamespace(idf=idf, documents=C.Documents(corpus))
     server, pool = stack.build(config, cfg, params, world, tok, prog_index)
     phase("compile")
     try:
         n_bm25 = _warm_bm25(idx, prog_index, pipe["retrieve_h"],
                             pipe["postings_budget"])
-        _warm_scorers(pool, cfg, config["serving"]["buckets"])
+        family.warm(pool, cfg, config["serving"]["buckets"])
         with _connector(server.address)() as client:
             for i in range(0, len(warm_q), batch):
                 if traffic["loop"] == "open":
@@ -350,7 +338,7 @@ def setup(cell: Cell, seed: int, n_timed: int, require_chip: bool = True,
         f"postings={len(idx.post_docs)} queries={len(qs) - n_warm} "
         f"postings_over_budget_share={over:.4f}")
     return Setup(corpus, idx, server, pool, qs, n_warm, key_bits, m,
-                 devices, setup_s)
+                 family, devices, setup_s)
 
 
 def drive(cell: Cell, st: Setup, seconds: float, first: int = 0,
@@ -509,15 +497,15 @@ def _check(cell: Cell, st: Setup, reqs, seed: int) -> List[Dict]:
     if done:
         pick.add(max(range(len(done)),
                      key=lambda i: len(st.words(done[i][0]))))
-    W = reference.init_weights(st.key_bits, st.model)
+    W = st.family.reference_weights(st.key_bits, st.model)
     result = reference.Check()
     limits = conf["limits"]
     tie = 2.0 * limits["score_gap"]
     for i in sorted(pick):
         q, ranking = done[i]
-        reference.check_query(result, st.corpus, st.index, W,
-                              cell.config["pipeline"], st.model["max_len"],
-                              st.words(q), ranking, tie)
+        reference.check_query(result, st.corpus, st.index,
+                              cell.config["pipeline"], st.family, W,
+                              st.model, st.words(q), ranking, tie)
     log(f"# checked {result.queries} queries, {result.items} served items: "
         f"retrieval misses {result.retrieval_misses}, rank misses "
         f"{result.rank_misses}")

@@ -1,7 +1,8 @@
-"""The harness end to end on the CPU at a tiny size: cells, mixes and
-metrics found by name, the result line's shape, the refusals off a TPU
-and without the program, and ``correct`` turning false when the served
-path is broken underneath or the bf16 control stands in for it."""
+"""The harness end to end on the CPU at a tiny size: cells, mixes,
+metrics and model families found by name, the result line's shape, the
+refusals off a TPU and without the program, and ``correct`` turning false
+when the served path is broken underneath or the bf16 control stands in
+for it."""
 import json
 import os
 import shutil
@@ -10,7 +11,7 @@ import sys
 
 import pytest
 
-from bench import control, harness
+from bench import control, families, harness
 
 from benchtree import ROOT, tiny_tree
 
@@ -90,6 +91,49 @@ def test_new_cell_mix_and_metric_found_by_name(tmp_path):
     json.dumps(traced)
 
 
+COPY_FAMILY = '''"""sm-cnn again, under another family name."""
+from bench.families.sm_cnn import *  # noqa: F401,F403
+'''
+
+
+def test_new_family_is_a_new_file_and_an_entry(tmp_path):
+    root = tiny_tree(tmp_path)
+    (root / "bench/families/sm_cnn_copy.py").write_text(COPY_FAMILY)
+    conf = json.loads((root / "bench/configs/sm-cnn-trecqa.json").read_text())
+    conf["family"] = "sm-cnn-copy"
+    (root / "bench/configs/sm-cnn-copy.json").write_text(json.dumps(conf))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "sm-cnn-copy", "source": "test",
+                            "file": "bench/configs/sm-cnn-copy.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "copy-bulk", "config": "sm-cnn-copy",
+                              "traffic": "bulk-4x4", "chips": 1,
+                              "why": "test"})
+    for m in spec["end_to_end"]:
+        if m["name"] == "qps":
+            m["workloads"].append("copy-bulk")
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    cell = harness.load_cell(root, "copy-bulk")
+    family = families.load(root, cell.config)
+    assert family.__file__ == str(root / "bench/families/sm_cnn_copy.py")
+    out = harness.run(cell, 2**31 + 29, 1.0, trace=False,
+                      require_chip=False)
+    assert out["correct"], out["compared"]
+    assert sorted(out["metrics"]) == ["qps", "setup_s"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+
+
+def test_unknown_family_names_the_missing_module(tiny):
+    path = tiny / "bench/configs/sm-cnn-trecqa.json"
+    conf = json.loads(path.read_text())
+    conf["family"] = "no-such-family"
+    path.write_text(json.dumps(conf))
+    cell = harness.load_cell(tiny, "trecqa-interactive")
+    with pytest.raises(FileNotFoundError,
+                       match="bench/families/no_such_family.py"):
+        harness.run(cell, 3, 1.0, trace=False, require_chip=False)
+
+
 def _scores_altered(monkeypatch):
     from repro.core import backends
     call = backends.Scorer.__call__
@@ -109,12 +153,14 @@ def _retrieval_altered(monkeypatch):
     monkeypatch.setattr(bm25, "retrieve_many", altered)
 
 
+@pytest.mark.parametrize("workload", ["msmarco-bulk", "trecqa-bulk"])
 @pytest.mark.parametrize("fault,number", [
     (_scores_altered, "score_gap"),
     (_retrieval_altered, "misses"),
 ])
-def test_broken_served_path_is_not_correct(tiny, monkeypatch, fault, number):
-    cell = harness.load_cell(tiny, "msmarco-bulk")
+def test_broken_served_path_is_not_correct(tiny, monkeypatch, fault, number,
+                                           workload):
+    cell = harness.load_cell(tiny, workload)
     fault(monkeypatch)
     out = harness.run(cell, 11, 1.0, trace=False, require_chip=False)
     row = out["compared"][number]
@@ -143,7 +189,8 @@ def _shed_every_third_in_the_window(monkeypatch):
     monkeypatch.setattr(harness, "drive", driving)
 
 
-@pytest.mark.parametrize("workload", ["trecqa-interactive", "msmarco-bulk"])
+@pytest.mark.parametrize("workload", ["trecqa-interactive", "msmarco-bulk",
+                                      "trecqa-bulk"])
 def test_shed_requests_are_not_correct(tiny, monkeypatch, workload):
     cell = harness.load_cell(tiny, workload)
     _shed_every_third_in_the_window(monkeypatch)
@@ -154,7 +201,8 @@ def test_shed_requests_are_not_correct(tiny, monkeypatch, workload):
     assert out["correct"] is False
 
 
-@pytest.mark.parametrize("workload", ["trecqa-interactive", "msmarco-bulk"])
+@pytest.mark.parametrize("workload", ["trecqa-interactive", "msmarco-bulk",
+                                      "trecqa-bulk"])
 def test_bf16_control_is_not_correct(tiny, workload):
     cell = harness.load_cell(tiny, workload)
     for seed in (21, 2**31 + 22):

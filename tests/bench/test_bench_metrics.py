@@ -4,7 +4,7 @@ import types
 
 import pytest
 
-from bench import flops, harness
+from bench import flops, harness, loadgen
 from benchtree import ROOT
 
 
@@ -67,3 +67,15 @@ def test_kernel_and_device_readers_need_a_trace():
         100 * least / 1e-4)
     assert idle(_run(trace=trace)) == pytest.approx(75.0)
     assert roof(_run(SPANS, REGISTRY)) is None and idle(_run()) is None
+
+
+def test_tail_reader_reads_every_request_from_its_scheduled_arrival():
+    read = harness.load_reader(ROOT, "latency_p95_ms.interactive")
+    reqs = []
+    for i in range(100):
+        r = loadgen.Request([i], t_sched=0.01 * i, rankings=[[]])
+        r.t_done = r.t_sched + 0.001 * (i + 1)       # 1 ms .. 100 ms
+        reqs.append(r)
+    reqs[0].rankings, reqs[0].error = None, "shed"  # a failed one counts too
+    assert read(_run(requests=reqs)) == pytest.approx(95.05)
+    assert read(_run()) is None
